@@ -171,7 +171,7 @@ def _time(fn, *, repeats: int) -> float:
     return float(np.min(ts) * 1e6)
 
 
-def _bench_cases(smoke: bool):
+def bench_cases(smoke: bool):
     """(kernel, shape, make-args, pallas fn, jnp fn, err fn) per kernel."""
     r = np.random.RandomState(42)
     s = 128 if smoke else 256
@@ -333,7 +333,7 @@ def records(smoke: bool = False):
     dtype-derived precision policy — plus the int8 fixed-point rows."""
     repeats = 1 if smoke else 3
     out = []
-    for kernel, args_np, pallas_fn, jnp_fn, err_fn in _bench_cases(smoke):
+    for kernel, args_np, pallas_fn, jnp_fn, err_fn in bench_cases(smoke):
         dtypes = ("float32",) if kernel == "gs_adam" else (
             "float32", "bfloat16")
         for dtype_name in dtypes:

@@ -68,6 +68,9 @@ def main() -> None:
     args = ap.parse_args()
     if args.serve_mesh and not args.serve:
         ap.error("--serve-mesh requires --serve")
+    from repro.launch.jax_cache import use_persistent_cache
+
+    use_persistent_cache()
 
     print("name,us_per_call,derived")
     if args.serve:
@@ -181,12 +184,9 @@ def main() -> None:
             from benchmarks import bench_kernels as mod
         else:
             from benchmarks import roofline as mod
-        try:
-            for r in mod.rows():
-                print(f"{r['name']},{r['us_per_call']},\"{r['derived']}\"")
-                sys.stdout.flush()
-        except Exception as e:  # keep the harness running section-wise
-            print(f"{section}__ERROR,0,\"{type(e).__name__}: {e}\"")
+        for r in mod.rows():
+            print(f"{r['name']},{r['us_per_call']},\"{r['derived']}\"")
+            sys.stdout.flush()
 
 
 if __name__ == "__main__":

@@ -71,7 +71,7 @@ class ArchConfig:
     policy_mode: str = "gs_feedback"  # exact | gs_pipelined | gs_feedback
     gs_p_bits: Optional[int] = None  # None -> derived (seed/iteration trade)
     gs_iters: Optional[int] = None  # None -> derived from dtype
-    kernel_impl: str = "jnp"  # jnp | pallas (pallas only on real TPU)
+    kernel_impl: str = "jnp"  # jnp | pallas (interpreted on CPU)
     quant: str = "none"  # none | int8: per-tensor int8 weights + int8 KV
     # arena + every GS division site through the fixed-point integer
     # datapath (core/fixed_point_jax) — the quantized serving route

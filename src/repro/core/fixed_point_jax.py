@@ -52,6 +52,16 @@ _MANT_MASK = 0x7FFFFF
 _F32_ONE_BITS = 1 << 23
 
 
+# Unsigned min/max as compare-and-select: Mosaic (the TPU kernel compiler)
+# has no unsigned min/max, and the kernels run this datapath.
+def _umin(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    return jnp.where(a < b, a, b)
+
+
+def _umax(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    return jnp.where(a > b, a, b)
+
+
 def msb32(x: jnp.ndarray) -> jnp.ndarray:
     """Leading-one index of uint32 registers (mirrors fixed_point.msb)."""
     e = jnp.zeros_like(x)
@@ -107,17 +117,17 @@ class FixedPointJax:
         F = jnp.uint32(self.frac_bits)
         ea, eb = msb32(a), msb32(b)
         fa, fb = a - (jnp.uint32(1) << ea), b - (jnp.uint32(1) << eb)
-        fa_s = jnp.where(ea <= F, fa << (F - jnp.minimum(ea, F)),
-                         fa >> (jnp.maximum(ea, F) - F))
-        fb_s = jnp.where(eb <= F, fb << (F - jnp.minimum(eb, F)),
-                         fb >> (jnp.maximum(eb, F) - F))
+        fa_s = jnp.where(ea <= F, fa << (F - _umin(ea, F)),
+                         fa >> (_umax(ea, F) - F))
+        fb_s = jnp.where(eb <= F, fb << (F - _umin(eb, F)),
+                         fb >> (_umax(eb, F) - F))
         s = fa_s + fb_s
         e2 = ea + eb + (s >> F)
         f2 = s & ((jnp.uint32(1) << F) - jnp.uint32(1))
         base = (jnp.uint32(1) << F) + f2
         two_f = jnp.uint32(2 * self.frac_bits)
-        shl = jnp.maximum(e2, two_f) - two_f
-        shr = jnp.minimum(two_f - jnp.minimum(e2, two_f), jnp.uint32(31))
+        shl = _umax(e2, two_f) - two_f
+        shr = _umin(two_f - _umin(e2, two_f), jnp.uint32(31))
         res = jnp.where(e2 >= two_f, base << shl, base >> shr)
         return jnp.where((a == 0) | (b == 0), jnp.uint32(0), res)
 

@@ -48,6 +48,18 @@ _F32_MANT_MASK = F32_MANT_MASK
 _F32_ONE_BITS = F32_ONE_BITS
 
 
+def interpret_flag(interpret: bool | None = None) -> bool:
+    """Pallas interpret mode: as given, else derived from the backend.
+
+    The CPU backend has no Mosaic compiler, so kernels run interpreted
+    there (tests); every other backend compiles them.  Callers pass an
+    explicit flag only to compile for a described chip from a CPU host.
+    """
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return interpret
+
+
 def fit_block(s: int, target: int) -> int:
     """Largest divisor of s that is <= target.
 
@@ -72,23 +84,56 @@ def rom_table_rsqrt(p: int = DEFAULT_P) -> jnp.ndarray:
     return jnp.asarray(lut.rsqrt_table_f32(p)).reshape(-1, 1)
 
 
-def rom_gather(idx: jnp.ndarray, table_ref_value: jnp.ndarray, p: int) -> jnp.ndarray:
+ROM_ROW_GROUP = 8  # tile rows per block-diagonal ROM read (one sublane tile)
+
+
+def _mxu(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    # HIGHEST: the one-hot picks exactly one table word, which must reach
+    # the output unrounded (the fixed-point ROM words are 14-bit integers)
+    return jax.lax.dot_general(
+        a, b, dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
+def rom_gather(idx: jnp.ndarray, table: jnp.ndarray, p: int) -> jnp.ndarray:
     """ROM read via one-hot matmul on the MXU.
 
-    idx: int32 array of any shape with values in [0, 2^p).
-    table_ref_value: (2^p, 1) float32 table (already loaded from the ref).
-    Returns float32 of idx's shape.
+    idx: (rows, cols) int32 with values in [0, 2^p); rows is a multiple of
+    8 when cols > 1.  table: (2^p, 1) float32 (already loaded from the
+    ref).  Returns float32 of idx's shape, bit-exact table words.
+
+    Nothing is reshaped across the lane axis, which Mosaic cannot lay out:
+
+    * a column of per-row statistics (cols == 1) broadcasts along the
+      lanes into a (rows, 2^p) one-hot against the (2^p, 1) table;
+    * a (rows, cols) tile reads 8 rows at a time: each row's indices
+      broadcast down the sublanes into a (2^p, cols) one-hot, the 8 are
+      stacked into (8·2^p, cols), and a block-diagonal (8, 8·2^p) copy
+      of the table contracts them to the (8, cols) words.
     """
-    flat = idx.reshape(-1)
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (flat.shape[0], 1 << p), 1)
-    onehot = (flat[:, None] == lanes).astype(jnp.float32)
-    vals = jax.lax.dot_general(
-        onehot,
-        table_ref_value,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    return vals.reshape(idx.shape)
+    rows, cols = idx.shape
+    k = 1 << p
+    if cols == 1:
+        lanes = jax.lax.broadcasted_iota(jnp.int32, (rows, k), 1)
+        return _mxu((idx == lanes).astype(jnp.float32), table)
+    g = min(ROM_ROW_GROUP, rows)
+    # the (k, 1) column as a (1, k) row, by a sublane reduction
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (k, k), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (k, k), 1))
+    tab_row = jnp.sum(jnp.where(eye, table, 0.0), axis=0, keepdims=True)
+    blk_row = jax.lax.broadcasted_iota(jnp.int32, (g, g * k), 0)
+    blk_col = jax.lax.broadcasted_iota(jnp.int32, (g, g * k), 1) // k
+    diag = jnp.where(blk_row == blk_col,
+                     jnp.concatenate([tab_row] * g, axis=1), 0.0)
+    words = jax.lax.broadcasted_iota(jnp.int32, (k, cols), 0)
+    out = []
+    for r0 in range(0, rows, g):
+        onehot = jnp.concatenate(
+            [(jnp.broadcast_to(idx[r:r + 1, :], (k, cols)) == words)
+             .astype(jnp.float32) for r in range(r0, r0 + g)], axis=0)
+        out.append(_mxu(diag, onehot))
+    return jnp.concatenate(out, axis=0)
 
 
 def split_fields(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:

@@ -56,6 +56,16 @@ DEFAULT_BLOCK_KV = 128
 NEG_INF = -1e30
 
 
+def _mm(a, b, contract, operand_dtype):
+    """Tile matmul with float32 accumulation.  Float32 operands take the
+    MXU's multi-pass path so their products stay float32 (one pass would
+    round them to bf16); bf16 operands are exact in one pass."""
+    prec = (jax.lax.Precision.HIGHEST if operand_dtype == jnp.float32
+            else None)
+    return jax.lax.dot_general(a, b, (contract, ((), ())), precision=prec,
+                               preferred_element_type=jnp.float32)
+
+
 def _kernel(q_ref, k_ref, v_ref, tab_ref, o_ref, *rest, sm_scale, causal,
             block_q, block_kv, n_kv_blocks, p, iters, variant,
             save_residuals):
@@ -76,9 +86,7 @@ def _kernel(q_ref, k_ref, v_ref, tab_ref, o_ref, *rest, sm_scale, causal,
         q = q_ref[0, 0].astype(jnp.float32)  # (bq, D)
         k = k_ref[0, 0].astype(jnp.float32)  # (bkv, D)
         v = v_ref[0, 0].astype(jnp.float32)  # (bkv, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale  # (bq, bkv)
+        s = _mm(q, k, ((1,), (1,)), q_ref.dtype) * sm_scale  # (bq, bkv)
         if causal:
             rows = iq * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 0
@@ -94,9 +102,8 @@ def _kernel(q_ref, k_ref, v_ref, tab_ref, o_ref, *rest, sm_scale, causal,
         alpha = jnp.exp(m_prev - m_new)  # rescale of the old accumulator
         e = jnp.exp(s - m_new)
         l_new = l_prev * alpha + jnp.sum(e, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            e, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        acc_ref[...] = acc_ref[...] * alpha + _mm(e, v, ((1,), (0,)),
+                                                  q_ref.dtype)
         m_ref[...] = m_new
         l_ref[...] = l_new
 
@@ -116,8 +123,8 @@ def _kernel(q_ref, k_ref, v_ref, tab_ref, o_ref, *rest, sm_scale, causal,
         )
         o_ref[0, 0] = (acc_ref[...] * inv).astype(o_ref.dtype)
         if save_residuals:
-            m_out[0, 0] = m_ref[...][:, 0]
-            l_out[0, 0] = l_ref[...][:, 0]
+            m_out[0, 0] = m_ref[...]
+            l_out[0, 0] = l_ref[...]
 
 
 def _fwd_call(q, k, v, causal, sm_scale, block_q, block_kv, p, iters,
@@ -133,11 +140,14 @@ def _fwd_call(q, k, v, causal, sm_scale, block_q, block_kv, p, iters,
         pl.BlockSpec((1, 1, block_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0))
     ]
     if save_residuals:
+        # per-row statistics keep a unit lane axis, (b, h, s, 1): a
+        # (block_q, 1) column tiles on the chip, a bare (block_q,) row of
+        # a (b, h, s) array does not
         for _ in range(2):  # m, l
-            out_shape.append(jax.ShapeDtypeStruct((b, h, s), jnp.float32))
+            out_shape.append(jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32))
             out_specs.append(
-                pl.BlockSpec((1, 1, block_q),
-                             lambda ib, ih, iq, ik: (ib, ih, iq))
+                pl.BlockSpec((1, 1, block_q, 1),
+                             lambda ib, ih, iq, ik: (ib, ih, iq, 0))
             )
     out = pl.pallas_call(
         functools.partial(
@@ -172,7 +182,7 @@ def _fwd_call(q, k, v, causal, sm_scale, block_q, block_kv, p, iters,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=common.interpret_flag(interpret),
     )(q, k, v, table)
     return out if save_residuals else (out, None, None)
 
@@ -187,15 +197,13 @@ def _p_tile(q_ref, k_ref, m_ref, l_ref, tab_ref, *, iq, ik, sm_scale, causal,
     """Recompute the (bq, bkv) probability tile from saved (m, l)."""
     q = q_ref[0, 0].astype(jnp.float32)
     k = k_ref[0, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * sm_scale
+    s = _mm(q, k, ((1,), (1,)), q_ref.dtype) * sm_scale
     if causal:
         rows = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         cols = ik * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(rows >= cols, s, NEG_INF)
-    m = m_ref[0, 0][:, None]  # (bq, 1)
-    l = jnp.maximum(l_ref[0, 0][:, None], 1e-30)
+    m = m_ref[0, 0]  # (bq, 1)
+    l = jnp.maximum(l_ref[0, 0], 1e-30)
     inv = common.recip_positive(
         l, tab_ref[...], p=p, iters=iters, variant=variant
     )  # Goldschmidt pass on the saved denominator — same datapath as fwd
@@ -219,14 +227,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, delta_ref,
         do = do_ref[0, 0].astype(jnp.float32)  # (bq, D)
         v = v_ref[0, 0].astype(jnp.float32)    # (bkv, D)
         k = k_ref[0, 0].astype(jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (bq, bkv)
-        delta = delta_ref[0, 0][:, None]  # (bq, 1)
+        dp = _mm(do, v, ((1,), (1,)), q_ref.dtype)  # (bq, bkv)
+        delta = delta_ref[0, 0]  # (bq, 1)
         ds = pt * (dp - delta) * sm_scale
-        acc_ref[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        acc_ref[...] += _mm(ds, k, ((1,), (0,)), q_ref.dtype)
 
     if causal:
         @pl.when(ik * block_kv <= iq * block_q + block_q - 1)
@@ -258,19 +262,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, delta_ref,
         q = q_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        dv_acc[...] += jax.lax.dot_general(
-            pt, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (bkv, D)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        delta = delta_ref[0, 0][:, None]
+        dv_acc[...] += _mm(pt, do, ((0,), (0,)), q_ref.dtype)  # (bkv, D)
+        dp = _mm(do, v, ((1,), (1,)), q_ref.dtype)
+        delta = delta_ref[0, 0]
         ds = pt * (dp - delta) * sm_scale
-        dk_acc[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (bkv, D)
+        dk_acc[...] += _mm(ds, q, ((0,), (0,)), q_ref.dtype)  # (bkv, D)
 
     if causal:
         # Block is fully masked iff every row index < every col index.
@@ -296,8 +292,9 @@ def _bwd_call(q, k, v, do, out, m, l, *, causal, sm_scale, block_q, block_kv,
     n_kv = s // block_kv
     table = common.rom_table(p)
     delta = jnp.sum(
-        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-    )  # (b, h, s)
+        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1,
+        keepdims=True,
+    )  # (b, h, s, 1), laid out like the saved m and l
 
     q_spec = pl.BlockSpec((1, 1, block_q, d),
                           lambda ib, ih, iq, ik: (ib, ih, iq, 0))
@@ -305,7 +302,8 @@ def _bwd_call(q, k, v, do, out, m, l, *, causal, sm_scale, block_q, block_kv,
         (1, 1, block_kv, d),
         lambda ib, ih, iq, ik, grp=group: (ib, ih // grp, ik, 0),
     )
-    row_spec = pl.BlockSpec((1, 1, block_q), lambda ib, ih, iq, ik: (ib, ih, iq))
+    row_spec = pl.BlockSpec((1, 1, block_q, 1),
+                            lambda ib, ih, iq, ik: (ib, ih, iq, 0))
     tab_spec = pl.BlockSpec((1 << p, 1), lambda ib, ih, iq, ik: (0, 0))
 
     dq = pl.pallas_call(
@@ -320,7 +318,7 @@ def _bwd_call(q, k, v, do, out, m, l, *, causal, sm_scale, block_q, block_kv,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
+        interpret=common.interpret_flag(interpret),
     )(q, k, v, do, m, l, delta, table)
 
     # dk/dv: grid transposed (kv outer, q inner); per-q-head outputs.
@@ -330,8 +328,8 @@ def _bwd_call(q, k, v, do, out, m, l, *, causal, sm_scale, block_q, block_kv,
         (1, 1, block_kv, d),
         lambda ib, ih, ik, iq, grp=group: (ib, ih // grp, ik, 0),
     )
-    rowT_spec = pl.BlockSpec((1, 1, block_q),
-                             lambda ib, ih, ik, iq: (ib, ih, iq))
+    rowT_spec = pl.BlockSpec((1, 1, block_q, 1),
+                             lambda ib, ih, ik, iq: (ib, ih, iq, 0))
     tabT_spec = pl.BlockSpec((1 << p, 1), lambda ib, ih, ik, iq: (0, 0))
     out_kv_spec = pl.BlockSpec((1, 1, block_kv, d),
                                lambda ib, ih, ik, iq: (ib, ih, ik, 0))
@@ -347,7 +345,7 @@ def _bwd_call(q, k, v, do, out, m, l, *, causal, sm_scale, block_q, block_kv,
         out_specs=[out_kv_spec, out_kv_spec],
         out_shape=[jax.ShapeDtypeStruct((b, h, s, d), jnp.float32)] * 2,
         scratch_shapes=[pltpu.VMEM((block_kv, d), jnp.float32)] * 2,
-        interpret=interpret,
+        interpret=common.interpret_flag(interpret),
     )(q, k, v, do, m, l, delta, table)
 
     # GQA: fold the per-q-head gradients back onto the KV heads.
@@ -425,7 +423,7 @@ def flash_attention(
     p: int = common.DEFAULT_P,
     iters: int = 2,
     variant: str = "feedback",
-    interpret: bool = True,
+    interpret: bool | None = None,
     block_q_bwd: int | None = None,
     block_kv_bwd: int | None = None,
 ) -> jnp.ndarray:
@@ -460,7 +458,7 @@ def flash_attention_bwd_bench(
     p: int = common.DEFAULT_P,
     iters: int = 2,
     variant: str = "feedback",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Autotuner entry for the backward kernels (``flash_attention_bwd``).
 

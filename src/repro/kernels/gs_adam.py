@@ -83,7 +83,7 @@ def gs_adam_update(
     iters: int = 2,
     variant: str = "feedback",
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """One fused AdamW step on a flat (or any-shape) parameter tensor.
 
@@ -136,7 +136,7 @@ def gs_adam_update(
             jax.ShapeDtypeStruct((rows_pad, cols), jnp.float32),
             jax.ShapeDtypeStruct((rows_pad, cols), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=common.interpret_flag(interpret),
     )(p2, g2, m2, v2, bc, common.rom_table(p), common.rom_table_rsqrt(p))
 
     unflat = lambda x: x.reshape(-1)[:n].reshape(orig_shape)

@@ -63,7 +63,9 @@ def fixed_rsqrt_rom_table(p: int) -> jnp.ndarray:
 def _seed_from_table(idx, table, p: int, frac_bits: int) -> jnp.ndarray:
     """One-hot ROM read → uint32 register left-aligned to frac_bits."""
     word = common.rom_gather(idx, table, p)  # exact: words ≤ 2^(p+2) ≤ 2^14
-    return word.astype(jnp.uint32) << jnp.uint32(frac_bits - (p + 2))
+    # through int32: Mosaic has no f32 -> uint32 cast (the word is positive)
+    return (word.astype(jnp.int32).astype(jnp.uint32)
+            << jnp.uint32(frac_bits - (p + 2)))
 
 
 def _recip_reg(dp: FixedPointJax, m_reg, idx, table, *, iters, variant):
@@ -75,7 +77,10 @@ def _recip_reg(dp: FixedPointJax, m_reg, idx, table, *, iters, variant):
 
 
 def _reg_to_f32(reg, frac_bits: int) -> jnp.ndarray:
-    return reg.astype(jnp.float32) * np.float32(2.0 ** -frac_bits)
+    # through int32: Mosaic has no uint32 -> f32 cast; every register read
+    # out here is a value < 2 in ≤ 30 fraction bits, so below 2^31
+    return (reg.astype(jnp.int32).astype(jnp.float32)
+            * np.float32(2.0 ** -frac_bits))
 
 
 # ---------------------------------------------------------------------------
@@ -118,7 +123,7 @@ def gs_fixed_recip(
     variant: str = "feedback",
     mitchell_iters: int = 0,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """1/(x·scale) for int8 x (any shape), elementwise, f32 out."""
     orig_shape = x.shape
@@ -144,7 +149,7 @@ def gs_fixed_recip(
         ],
         out_specs=pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_pad, cols), jnp.float32),
-        interpret=interpret,
+        interpret=common.interpret_flag(interpret),
     )(x2, table, inv_scale)
     return out.reshape(-1)[:n].reshape(orig_shape)
 
@@ -166,8 +171,9 @@ def _softmax_kernel(x_ref, tab_ref, s_ref, o_ref, *, p, frac_bits, iters,
     s = jnp.sum(e, axis=-1, keepdims=True)  # ∈ [1, d]: a positive normal
     eb, mant, _ = _peel(s)
     m_reg = _mant_to_reg(mant, frac_bits)
-    idx = jnp.clip((mant & 0x7FFFFF) >> jnp.uint32(23 - p),
-                   0, (1 << p) - 1).astype(jnp.int32)
+    # clip in int32: Mosaic has no unsigned min/max (the index is < 2^23)
+    idx = jnp.clip(((mant & 0x7FFFFF) >> jnp.uint32(23 - p)).astype(jnp.int32),
+                   0, (1 << p) - 1)
     q = _recip_reg(dp, m_reg, idx, tab_ref[...], iters=iters,
                    variant=variant)
     inv = _reg_to_f32(q, frac_bits) * common.pow2_from_biased(254 - eb)
@@ -187,7 +193,7 @@ def gs_fixed_softmax(
     variant: str = "feedback",
     mitchell_iters: int = 0,
     block_rows: int = DEFAULT_ROW_BLOCK,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """softmax(x·scale) over the last axis of int8 x, f32 out."""
     orig_shape = x.shape
@@ -214,7 +220,7 @@ def gs_fixed_softmax(
         ],
         out_specs=pl.BlockSpec((block_rows, d_pad), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_pad, d_pad), jnp.float32),
-        interpret=interpret,
+        interpret=common.interpret_flag(interpret),
     )(x2, table, inv_scale)
     return out[:rows, :d].reshape(orig_shape)
 
@@ -262,7 +268,7 @@ def gs_fixed_rmsnorm(
     variant: str = "feedback",  # accepted for dispatch uniformity; the
     mitchell_iters: int = 0,  # rsqrt core is feedback-shaped & exact-mult
     block_rows: int = DEFAULT_ROW_BLOCK,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """RMSNorm of (x·scale) over the last axis; int8 x, f32 out."""
     del variant, mitchell_iters
@@ -291,6 +297,6 @@ def gs_fixed_rmsnorm(
         ],
         out_specs=pl.BlockSpec((block_rows, d_pad), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_pad, d_pad), jnp.float32),
-        interpret=interpret,
+        interpret=common.interpret_flag(interpret),
     )(x2, g2, table, sc)
     return out[:rows, :d].reshape(orig_shape)

@@ -78,7 +78,7 @@ def _run(x, *, p, iters, variant, block_rows, interpret):
         ],
         out_specs=pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_pad, cols), jnp.float32),
-        interpret=interpret,
+        interpret=common.interpret_flag(interpret),
     )(x2, table)
     return out.reshape(-1)[:n].reshape(orig_shape).astype(orig_dtype)
 
@@ -114,7 +114,7 @@ def gs_recip(
     iters: int = 2,
     variant: str = "feedback",
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Reciprocal of x (any shape), elementwise, via the Pallas datapath."""
     return _recip(x, p, iters, variant, block_rows, interpret)

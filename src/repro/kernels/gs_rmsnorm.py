@@ -74,7 +74,7 @@ def _run(x, gain, *, eps, p, iters, variant, block_rows, interpret,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=common.interpret_flag(interpret),
     )(x2, g2, table)
     if save_inv:
         y, inv = out
@@ -125,7 +125,7 @@ def gs_rmsnorm(
     iters: int = 2,
     variant: str = "feedback",
     block_rows: int = 8,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """RMSNorm over the last axis; gain has shape (d,)."""
     return _rmsnorm(x, gain, eps, p, iters, variant, block_rows, interpret)

@@ -85,7 +85,7 @@ def _run(x, *, p, iters, variant, block_rows, interpret, mode):
         out_specs=[pl.BlockSpec((block_rows, cols), lambda i: (i, 0))] * n_out
         if n_out > 1 else pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
         out_shape=[out_sds] * n_out if n_out > 1 else out_sds,
-        interpret=interpret,
+        interpret=common.interpret_flag(interpret),
     )(x2, table)
     outs = out if n_out > 1 else (out,)
     trimmed = tuple(
@@ -139,7 +139,7 @@ _sqrt.defvjp(_sqrt_fwd, _sqrt_bwd)
 )
 def gs_rsqrt(x, *, p: int = common.DEFAULT_P, iters: int = 2,
              variant: str = "feedback", block_rows: int = DEFAULT_BLOCK_ROWS,
-             interpret: bool = True):
+             interpret: bool | None = None):
     return _rsqrt(x, p, iters, variant, block_rows, interpret)
 
 
@@ -148,5 +148,5 @@ def gs_rsqrt(x, *, p: int = common.DEFAULT_P, iters: int = 2,
 )
 def gs_sqrt(x, *, p: int = common.DEFAULT_P, iters: int = 2,
             variant: str = "feedback", block_rows: int = DEFAULT_BLOCK_ROWS,
-            interpret: bool = True):
+            interpret: bool | None = None):
     return _sqrt(x, p, iters, variant, block_rows, interpret)

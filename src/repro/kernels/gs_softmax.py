@@ -61,7 +61,7 @@ def _run(x, *, p, iters, variant, block_rows, interpret):
         ],
         out_specs=pl.BlockSpec((block_rows, cols_pad), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_pad, cols_pad), orig_dtype),
-        interpret=interpret,
+        interpret=common.interpret_flag(interpret),
     )(x2, table)
     return out[:rows, :cols].reshape(orig_shape)
 
@@ -98,7 +98,7 @@ def gs_softmax(
     iters: int = 2,
     variant: str = "feedback",
     block_rows: int = 8,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Softmax over the last axis of x (any leading shape)."""
     return _softmax(x, p, iters, variant, block_rows, interpret)

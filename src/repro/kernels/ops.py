@@ -14,19 +14,21 @@ Defaults leave ``(p, iters)`` to the operand dtype's
 the seed literals (7, 2) — cold-start fp32 behavior is bit-identical —
 while bf16 runs seed-only (8, 0) and fp16 single-pass (7, 1).
 
-``interpret`` defaults to True because this container is CPU-only; on a
-real TPU deployment set ``REPRO_PALLAS_INTERPRET=0`` (or pass
-``interpret=False``) and the same BlockSpecs compile via Mosaic.
+``interpret`` is derived from the backend
+(:func:`repro.kernels.common.interpret_flag`): on the CPU backend (tests,
+``JAX_PLATFORMS=cpu``) the kernels run in Pallas interpret mode; on a TPU
+the same BlockSpecs compile through Mosaic.  An explicit
+``interpret=False`` compiles for a described chip from a CPU host
+(``tests/test_tpu_compile.py``).
 
-Every front-end routes through :func:`dispatch.call_with_fallback`: a
-kernel that fails to trace/lower/compile (Pallas interpret bug, Mosaic
-hole on a new backend, poisoned tuning-cache config) downgrades to its
-jnp oracle (:mod:`repro.kernels.ref`; exact-arithmetic references for
-the fixed-point kernels) instead of propagating — serving degrades,
-it doesn't die.  Downgrades are counted per kernel
+Every front-end routes through :func:`dispatch.call_with_fallback`.  By
+default a kernel that fails to trace/lower/compile raises.  With the
+fallback opted in (``REPRO_KERNEL_FALLBACK=1`` or
+``dispatch.enable_fallback(True)``) it downgrades to its jnp oracle
+(:mod:`repro.kernels.ref`; exact-arithmetic references for the
+fixed-point kernels) instead, counted per kernel
 (``dispatch.fallback_stats()``; surfaced as
-``ServeMetrics.kernel_fallbacks``); disable the route with
-``REPRO_KERNEL_FALLBACK=0`` when a failure must stay visible.
+``ServeMetrics.kernel_fallbacks``).
 
 All ops are differentiable: each kernel carries a ``custom_vjp`` whose
 rule runs on saved forward outputs (quotient / rsqrt / softmax /
@@ -56,7 +58,6 @@ from repro.kernels.gs_rsqrt import gs_rsqrt as _gs_rsqrt
 from repro.kernels.gs_rsqrt import gs_sqrt as _gs_sqrt
 from repro.kernels.gs_softmax import gs_softmax as _gs_softmax
 from repro.kernels.tuning import dispatch
-from repro.kernels.tuning.dispatch import interpret_default  # noqa: F401
 
 __all__ = [
     "flash_attention",
@@ -69,7 +70,6 @@ __all__ = [
     "gs_rsqrt",
     "gs_softmax",
     "gs_sqrt",
-    "interpret_default",
 ]
 
 

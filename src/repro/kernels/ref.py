@@ -86,18 +86,22 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
 def attention_exact(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, sm_scale: Optional[float] = None) -> jnp.ndarray:
+    # HIGHEST: a TPU's default matmul rounds float32 operands to bf16
+    hi = jax.lax.Precision.HIGHEST
     b, h, s, d = q.shape
     kh = k.shape[1]
     group = h // kh
     if sm_scale is None:
         sm_scale = 1.0 / (d ** 0.5)
     qf = q.astype(jnp.float32).reshape(b, kh, group, s, d)
-    logits = jnp.einsum("bkgsd,bktd->bkgst", qf, k.astype(jnp.float32)) * sm_scale
+    logits = jnp.einsum("bkgsd,bktd->bkgst", qf, k.astype(jnp.float32),
+                        precision=hi) * sm_scale
     if causal:
         mask = jnp.tril(jnp.ones((s, s), dtype=bool))
         logits = jnp.where(mask, logits, -jnp.inf)
     probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgst,bktd->bkgsd", probs, v.astype(jnp.float32))
+    out = jnp.einsum("bkgst,bktd->bkgsd", probs, v.astype(jnp.float32),
+                     precision=hi)
     return out.reshape(b, h, s, d).astype(q.dtype)
 
 

@@ -29,7 +29,6 @@ from repro.kernels.tuning.cache import (
 from repro.kernels.tuning.dispatch import (
     enable_tuning,
     finalize,
-    interpret_default,
     resolve,
     tuning_enabled,
 )
@@ -50,7 +49,6 @@ __all__ = [
     "finalize",
     "get_cache",
     "get_spec",
-    "interpret_default",
     "resolve",
     "shape_bucket",
     "time_call",

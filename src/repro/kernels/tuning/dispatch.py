@@ -25,6 +25,7 @@ from typing import Any, Callable, Dict, Mapping, Optional, Sequence
 
 import jax
 
+from repro.kernels import common
 from repro.kernels.tuning import cache as cache_mod
 from repro.kernels.tuning import registry
 
@@ -37,11 +38,6 @@ _fallback_counts: Counter = Counter()
 _resolve_counts: Counter = Counter()
 _tune_hits: Counter = Counter()
 _tune_misses: Counter = Counter()
-
-
-def interpret_default() -> bool:
-    """interpret=True unless REPRO_PALLAS_INTERPRET=0 (real-TPU deploys)."""
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 def tuning_enabled() -> bool:
@@ -57,21 +53,19 @@ def enable_tuning(on: Optional[bool] = True) -> None:
     _enabled_override = on
 
 
-# -- pallas -> jnp fallback route --------------------------------------------
-# Graceful degradation: a kernel that fails to trace/lower (a Pallas
-# interpret bug, a Mosaic lowering hole on a new backend, a bad tuned
-# config from a foreign cache entry) downgrades to its jnp oracle
-# (kernels/ref.py) instead of killing the request — serving keeps
-# answering, slower.  The downgrade is counted per kernel so the serving
+# -- pallas -> jnp fallback route (opt-in) -----------------------------------
+# A kernel that fails to trace/lower raises by default: on the measured
+# path a Mosaic lowering hole must fail the run, not be timed as jnp.
+# Opted in (REPRO_KERNEL_FALLBACK=1 or enable_fallback(True)), the failing
+# kernel downgrades to its jnp oracle (kernels/ref.py) and serving keeps
+# answering, slower; the downgrade is counted per kernel so the serving
 # metrics (ServeMetrics.kernel_fallbacks) and operators can see it.
-# On by default; kill with REPRO_KERNEL_FALLBACK=0 (tests/benchmarks
-# that must observe the real kernel failure).
 
 
 def fallback_enabled() -> bool:
     if _fallback_override is not None:
         return _fallback_override
-    return os.environ.get(ENV_FALLBACK, "1").lower() not in ("0", "", "false")
+    return os.environ.get(ENV_FALLBACK, "0").lower() not in ("0", "", "false")
 
 
 def enable_fallback(on: Optional[bool] = True) -> None:
@@ -139,9 +133,10 @@ def reset_dispatch_stats() -> None:
 
 def call_with_fallback(kernel: str, primary: Callable[[], Any],
                        fallback: Callable[[], Any]) -> Any:
-    """Run ``primary`` (the Pallas kernel call, as a thunk); on any
-    exception, record the downgrade and run ``fallback`` (the jnp
-    oracle).  Resolution and the kernels run at trace time, so this
+    """Run ``primary`` (the Pallas kernel call, as a thunk).  With the
+    route opted in (:func:`fallback_enabled`), an exception records the
+    downgrade and runs ``fallback`` (the jnp oracle) instead; otherwise
+    it propagates.  Resolution and the kernels run at trace time, so this
     catches trace/lower/compile failures — exactly where kernel faults
     surface in this stack (interpret mode included)."""
     if not fallback_enabled():
@@ -162,15 +157,16 @@ def call_with_fallback(kernel: str, primary: Callable[[], Any],
 def finalize(config: Mapping[str, Any], dtype=None) -> Dict[str, Any]:
     """Concretize deferred values.
 
-    ``interpret=None`` → env default; ``p``/``iters`` = None → the
-    :func:`repro.core.goldschmidt.precision_policy` pair for ``dtype``
+    ``interpret=None`` → derived from the backend
+    (:func:`repro.kernels.common.interpret_flag`); ``p``/``iters`` = None →
+    the :func:`repro.core.goldschmidt.precision_policy` pair for ``dtype``
     ((7, 2) for fp32 — the seed literals — seed-only for bf16 with p ≥ 8).
     A pinned ``p`` derives its matching pass count; a pinned ``iters``
     keeps the default table (see ``resolve_precision``).
     """
     cfg = dict(config)
     if cfg.get("interpret") is None:
-        cfg["interpret"] = interpret_default()
+        cfg["interpret"] = common.interpret_flag()
     if "frac_bits" in cfg:
         # Fixed-point kernel: the (p, iters) pair comes from the measured
         # fixed frontier (formats.fixed_precision_policy), budgeted at the

@@ -14,8 +14,8 @@ into a runtime policy:
                     via :func:`repro.core.goldschmidt.precision_policy`;
                     the (p, iters) product is pruned to pairs that reach
                     the dtype's target bits with no wasted pass,
-* ``interpret``   — interpret-mode vs Mosaic-compiled pallas_call
-                    (candidate set depends on the backend).
+* ``interpret``   — not a choice: the one value the backend allows
+                    (interpreted on CPU, Mosaic-compiled elsewhere).
 
 ``defaults`` reproduce the seed's hard-coded literals exactly, so a cold
 cache (or tuning disabled) is behavior-identical to the pre-tuning tree.

@@ -16,17 +16,25 @@ runtime/sharding.py fsdp_axes).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: GSPMD propagates shardings and the
+    layers' ``with_sharding_constraint`` calls apply (Explicit axes, the
+    default since JAX 0.7, reject both)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh over however many (possibly fake) devices a test has."""
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def parse_mesh_spec(spec: str):
@@ -81,7 +89,7 @@ def make_serving_mesh(spec: str = "auto"):
         shape, axes = (1, jax.device_count()), ("data", "model")
     else:
         shape, axes = parse_mesh_spec(spec)
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 # Hardware constants for the roofline (TPU v5e-like, per chip).

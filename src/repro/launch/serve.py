@@ -41,8 +41,28 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.launch.jax_cache import use_persistent_cache
 from repro.models import api
+from repro.runtime import sharding as shr
 from repro.serving import Engine, EngineConfig, Request, SamplingParams
+
+
+def build_engine(cfg, ecfg: EngineConfig, *, seed: int = 0,
+                 mesh=None) -> Engine:
+    """The serving engine over seeded random weights.
+
+    With a ``mesh`` the weights are initialized straight into the
+    placement of the sharding rule table, so a model larger than one
+    device never has to exist whole on the first one.
+    """
+    key = jax.random.key(seed)
+    if mesh is None:
+        params = api.init(cfg, key)
+    else:
+        init = lambda k: api.init(cfg, k)  # noqa: E731
+        params = jax.jit(init, out_shardings=shr.tree_shardings(
+            mesh, jax.eval_shape(init, key)))(key)
+    return Engine(cfg, params, ecfg, mesh=mesh)
 
 
 def build_requests(args, cfg, rng: np.random.RandomState):
@@ -214,6 +234,7 @@ def main() -> None:
                          "(persists to the tuning cache) and serve with "
                          "tuned dispatch enabled")
     args = ap.parse_args()
+    use_persistent_cache()
 
     cfg = (configs.get_smoke(args.arch) if args.smoke
            else configs.get_config(args.arch))
@@ -257,13 +278,12 @@ def main() -> None:
 
         tracer = Tracer()
     rng = np.random.RandomState(args.seed)
-    params = api.init(cfg, jax.random.key(args.seed))
-    engine = Engine(cfg, params, EngineConfig(
+    engine = build_engine(cfg, EngineConfig(
         n_slots=args.batch, s_max=s_max, seed=args.seed, pool=args.pool,
         page_size=args.page_size, n_pages=args.pages,
         page_reserve=args.page_reserve,
         max_retries=args.max_retries, tracer=tracer),
-        mesh=mesh)
+        seed=args.seed, mesh=mesh)
     reqs = build_requests(args, cfg, rng)
     if not args.no_warmup:
         # compile prefill (per distinct length) + the tick up front so the

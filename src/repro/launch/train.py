@@ -28,6 +28,8 @@ import numpy as np
 from repro import configs
 from repro.checkpoint.store import config_fingerprint
 from repro.data.synthetic import SyntheticLM
+from repro.launch.jax_cache import use_persistent_cache
+from repro.launch.mesh import auto_mesh
 from repro.launch.steps import TrainHParams, make_train_step
 from repro.optim import adamw_init
 from repro.models import api
@@ -42,7 +44,7 @@ def parse_mesh(spec: str):
     names, sizes = spec.split("=")
     axes = tuple(names.split(","))
     shape = tuple(int(x) for x in sizes.split(","))
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def main() -> None:
@@ -66,6 +68,7 @@ def main() -> None:
                     help="override cfg.kernel_impl: 'pallas' trains through "
                          "the fused kernels (custom_vjp backward)")
     args = ap.parse_args()
+    use_persistent_cache()
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")

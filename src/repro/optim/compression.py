@@ -7,10 +7,11 @@ int32), dequantize with the psum'd per-pod scales, and keep the local
 quantization residual as error feedback added to the next step's gradient
 (EF14 — convergence-safe for SGD-family updates).
 
-Implemented as a ``shard_map`` whose specs reference only 'pod' (see the
-note in :func:`compressed_grad_fn` on why this jax version runs it fully
-manual rather than partial-auto).  Cross-pod gradient bytes drop 4x
-(fp32->int8) minus one scalar per leaf.
+Implemented as a fully manual ``jax.shard_map`` whose specs reference only
+'pod': unreferenced mesh axes see replicated operands inside, which is
+exact here because the pod mean is computed locally on each device after
+the int8 all-gather.  Cross-pod gradient bytes drop 4x (fp32->int8) minus
+one scalar per leaf.
 """
 
 from __future__ import annotations
@@ -20,7 +21,6 @@ from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -64,22 +64,14 @@ def compressed_grad_fn(
 
     Returns ``fn(params, batch, ef) -> (loss, grads, ef')`` where grads are
     the cross-pod EF-int8 mean and batch leaves are sharded over 'pod' on
-    their leading axis.  Only 'pod' is manual; 'data'/'model' stay GSPMD.
+    their leading axis.
     """
 
-    # NOTE on manual-axis scope: the seed called ``jax.shard_map`` with
-    # ``axis_names={axis}`` / ``check_vma`` — kwargs from a newer jax; this
-    # jax spells it ``jax.experimental.shard_map.shard_map`` with
-    # ``check_rep``, and its partial-manual form (``auto=``) trips an XLA
-    # SPMD partitioner check on the CPU backend.  So the wrapper runs
-    # fully manual: unreferenced mesh axes see replicated operands inside,
-    # which is exact for this wrapper (the pod-mean is computed locally
-    # per device after the int8 all-gather).
     def fn(params, batch, ef):
         @partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(P(), P(axis), P()), out_specs=(P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
         def run(params, batch, ef):
             loss, grads = jax.value_and_grad(loss_fn)(params, batch)

@@ -29,7 +29,7 @@ from __future__ import annotations
 from typing import Any, Optional, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # leaf name -> spec for the UNSTACKED rank (scan-group axis prepended
 # automatically when the actual rank is one higher).
@@ -73,15 +73,11 @@ _MOE_RULES = {  # rank-3 expert-stacked weights: EP over 'model'
 
 def abstract_mesh(axis_sizes: Tuple[int, ...],
                   axis_names: Tuple[str, ...]) -> "jax.sharding.AbstractMesh":
-    """Device-free mesh for rule/divisibility checks.
-
-    ``jax.sharding.AbstractMesh`` wants one ``((name, size), ...)`` shape
-    tuple, not the ``(sizes, names)`` pair ``Mesh`` takes — passing sizes
-    positionally lands a bare int where an iterable is expected
-    (``TypeError: 'int' object is not iterable``).  Single home for the
-    construction so callers can't get the pairing wrong.
-    """
-    return jax.sharding.AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    """Device-free mesh for rule/divisibility checks, with Auto axes like
+    every mesh :mod:`repro.launch.mesh` builds."""
+    return jax.sharding.AbstractMesh(
+        tuple(axis_sizes), tuple(axis_names),
+        axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def _path_names(path) -> Tuple[str, ...]:

@@ -252,8 +252,9 @@ class TestHypothesisProperties:
         from hypothesis import assume
 
         ref = np.float64(n) / np.float64(d)
-        # documented domain: normal-range results (subnormals flush, as on
-        # TPU hardware)
+        # documented domain: normal operands and normal-range results
+        # (subnormals flush to zero, as on TPU hardware)
+        assume(n == 0 or abs(n) >= 2.0 ** -126)
         assume(ref == 0 or 2.0 ** -125 < abs(ref) < 2.0 ** 127)
         q = float(gs.gs_divide(jnp.float32(n), jnp.float32(d)))
         if ref == 0:

@@ -100,7 +100,7 @@ class TestPolicyPairBounds:
     def test_reciprocal(self, dtype_name):
         dt, _, E = DTYPES[dtype_name]
         p, iters = pair_for(dt)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             x = jnp.asarray(_log_grid(E)).astype(dt)
             x64 = np.asarray(x, np.float64)
             got = gs.gs_reciprocal(x)
@@ -110,7 +110,7 @@ class TestPolicyPairBounds:
     def test_divide(self, dtype_name):
         dt, _, E = DTYPES[dtype_name]
         p, iters = pair_for(dt)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             x = jnp.asarray(_log_grid(E)).astype(dt)
             x64 = np.asarray(x, np.float64)
             n = x[::-1] * x.dtype.type(1.7)  # quotients stay in-window
@@ -122,7 +122,7 @@ class TestPolicyPairBounds:
     def test_rsqrt(self, dtype_name):
         dt, _, E = DTYPES[dtype_name]
         p, iters = pair_for(dt)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             x = jnp.abs(jnp.asarray(_log_grid(E)).astype(dt))
             x64 = np.asarray(x, np.float64)
             got = gs.gs_rsqrt(x)
@@ -133,7 +133,7 @@ class TestPolicyPairBounds:
     def test_sqrt(self, dtype_name):
         dt, _, E = DTYPES[dtype_name]
         p, iters = pair_for(dt)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             x = jnp.abs(jnp.asarray(_log_grid(E)).astype(dt))
             x64 = np.asarray(x, np.float64)
             got = gs.gs_sqrt(x)
@@ -189,7 +189,7 @@ class TestSpecialValues:
 
     def test_signed_zeros(self, dtype_name):
         dt = self._dt(dtype_name)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             z = jnp.asarray([0.0, -0.0], dt)
             r = np.asarray(gs.gs_reciprocal(z), np.float64)
             assert np.isposinf(r[0]) and np.isneginf(r[1])
@@ -208,7 +208,7 @@ class TestSpecialValues:
 
     def test_inf_nan(self, dtype_name):
         dt = self._dt(dtype_name)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             inf = jnp.asarray([np.inf, -np.inf], dt)
             r = np.asarray(gs.gs_reciprocal(inf), np.float64)
             assert r[0] == 0 and not np.signbit(r[0])
@@ -242,7 +242,7 @@ class TestSpecialValues:
         dt = self._dt(dtype_name)
         fi = jnp.finfo(dt)
         sub0 = float(fi.tiny) * 2.0 ** -(fi.nmant)  # smallest subnormal
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             x = jnp.asarray(np.asarray(
                 [float(fi.tiny) / 2, float(fi.tiny) / 4, sub0 * 3], np.float64
             ), dt)
@@ -264,7 +264,7 @@ class TestSpecialValues:
         """For the fp32 pair the iteration converges past every mantissa
         bit, so 1/2^k and rsqrt(4^k) round to the exact power of two."""
         dt = self._dt(dtype_name)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             k = jnp.asarray([2.0 ** e for e in range(-40, 41)], dt)
             got = gs.gs_reciprocal(k)
             ref = (1.0 / np.asarray(k, np.float64)).astype(jnp.float64)
@@ -285,7 +285,7 @@ class TestSpecialValues:
         # the f32 internal datapath caps the representable magnitude for
         # f64 operands — values beyond it saturate by contract
         mx = min(float(fi.max), float(jnp.finfo(jnp.float32).max))
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             x = jnp.asarray([mx, mx * 0.5, -mx], dt)
             x64 = np.asarray(x, np.float64)
             got = np.asarray(gs.gs_reciprocal(x), np.float64)

@@ -56,7 +56,8 @@ class TestShardedTraining:
             # single-logical-device result
             p1, o1, m1 = jax.jit(make_train_step(cfg, hp))(params, opt, batch)
 
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            from repro.launch.mesh import make_test_mesh
+            mesh = make_test_mesh((2, 4))
             psh = shr.tree_shardings(mesh, jax.eval_shape(lambda: params))
             osh = shr.tree_shardings(mesh, jax.eval_shape(lambda: opt))
             bsh = shr.batch_shardings(mesh, cfg, jax.eval_shape(lambda: batch), 8)
@@ -78,7 +79,8 @@ class TestShardedTraining:
             import json, jax, jax.numpy as jnp, numpy as np
             from repro.optim.compression import compressed_grad_fn, ef_init
 
-            mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+            from repro.launch.mesh import make_test_mesh
+            mesh = make_test_mesh((2, 2, 2), ("pod", "data", "model"))
             def loss_fn(p, batch):
                 x, y = batch["x"], batch["y"]
                 pred = x @ p["w"]
@@ -116,7 +118,8 @@ class TestShardedTraining:
             path = save_checkpoint(d, 3, params)
 
             # restore onto a DIFFERENT mesh shape (elastic path)
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            from repro.launch.mesh import make_test_mesh
+            mesh = make_test_mesh((4, 2))
             sh = shr.tree_shardings(mesh, jax.eval_shape(lambda: params))
             restored, manifest = load_checkpoint(
                 path, jax.eval_shape(lambda: params), shardings=sh)

@@ -496,6 +496,7 @@ class TestDispatchCounters:
             raise RuntimeError("injected kernel fault")
 
         monkeypatch.setattr(ops, "_gs_recip", boom)
+        monkeypatch.setattr(dispatch, "_fallback_override", True)  # opt in
         with warnings.catch_warnings():
             warnings.simplefilter("ignore", RuntimeWarning)
             np.asarray(ops.gs_recip(np.ones(4, np.float32)))

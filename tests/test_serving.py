@@ -565,12 +565,18 @@ class TestPagedServing:
         params = api.init(cfg, jax.random.key(28))
         rng = np.random.RandomState(28)
         prompts = [rng.randint(0, cfg.vocab, (4,)) for _ in range(2)]
-        # each stream stops at its own 3rd greedy token: 3 of the 18
-        # budgeted tokens -> 2 of the 6 worst-case pages get written
-        stops = [int(np.asarray(generate_sequential(
-            cfg, params,
-            Request(rid=9, prompt=p, max_new_tokens=18), s_max=22))[2])
-            for p in prompts]
+        # each stream stops at the first greedy token that has not
+        # occurred before and sits at index >= 2: a few of the 18 budgeted
+        # tokens, so fewer than the 6 worst-case pages get written, and
+        # never the first token (which would end before any overlap)
+        def stop_token(p):
+            s = [int(t) for t in np.asarray(generate_sequential(
+                cfg, params,
+                Request(rid=9, prompt=p, max_new_tokens=18), s_max=22))]
+            return next(t for j, t in enumerate(s) if j >= 2
+                        and t not in s[:j])
+
+        stops = [stop_token(p) for p in prompts]
 
         def trace():
             return [Request(rid=i, prompt=p, max_new_tokens=18,

@@ -448,6 +448,7 @@ class TestKernelFallback:
             raise RuntimeError("injected kernel fault")
 
         monkeypatch.setattr(ops, "_gs_recip", boom)
+        monkeypatch.setattr(dispatch, "_fallback_override", True)  # opt in
         with warnings.catch_warnings(record=True) as w:
             warnings.simplefilter("always")
             got = np.asarray(ops.gs_recip(x))
@@ -463,12 +464,17 @@ class TestKernelFallback:
             raise RuntimeError("injected kernel fault")
 
         monkeypatch.setattr(ops, "_gs_recip", boom)
-        dispatch.enable_fallback(False)
-        try:
-            with pytest.raises(RuntimeError, match="injected kernel"):
-                ops.gs_recip(np.ones(4, np.float32))
-        finally:
-            dispatch.enable_fallback(None)
+        monkeypatch.delenv(dispatch.ENV_FALLBACK, raising=False)
+        dispatch.reset_fallback_stats()
+        # off by default, and when switched off explicitly
+        for on in (None, False):
+            dispatch.enable_fallback(on)
+            try:
+                with pytest.raises(RuntimeError, match="injected kernel"):
+                    ops.gs_recip(np.ones(4, np.float32))
+            finally:
+                dispatch.enable_fallback(None)
+        assert dispatch.fallback_total() == 0
         dispatch.reset_fallback_stats()
 
 

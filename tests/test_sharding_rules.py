@@ -94,7 +94,8 @@ class TestActivationConstraints:
         assert y is x
 
     def test_constrain_applies_in_context(self):
-        mesh = jax.make_mesh((1,), ("model",))
+        from repro.launch.mesh import make_test_mesh
+        mesh = make_test_mesh((1,), ("model",))
         with shr.activation_context(mesh, ()):
             def f(x):
                 return shr.constrain(x, None, "model")
