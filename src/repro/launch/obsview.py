@@ -5,7 +5,8 @@
 Reads either export form (Chrome-trace JSON or JSONL — see
 ``repro.obs.export``) and prints the run at a glance: request count and
 finish-reason mix, per-phase latency distributions (queued / prefill /
-decode / tick), counter peaks, incident counts (preempt / retry /
+decode / tick), the engine's host phases (``engine.*``: p50 / p95 and
+the total per phase), counter peaks, incident counts (preempt / retry /
 quarantine / poison), and — when the exporter embedded the run's
 ``ServeMetrics`` in the metadata — the TTFT/ITL percentiles and the
 per-kernel fallback/dispatch breakdown.  The deep-dive view is the same
@@ -19,7 +20,7 @@ from collections import Counter as TallyCounter
 from typing import Dict, List
 
 from repro.obs import MetricsRegistry, load_events, request_chains
-from repro.obs.trace import COUNTER, INSTANT, SPAN
+from repro.obs.trace import COUNTER, INSTANT, PHASE_PREFIX, SPAN
 
 INCIDENT_EVENTS = ("preempt", "retry_backoff", "tick_retry", "quarantine",
                    "poison", "cache_poisoned", "admission_error",
@@ -63,6 +64,13 @@ def summarize_trace(events: List[tuple], meta: dict) -> List[str]:
         h = reg.histograms.get(phase)
         if h is not None and h.count:
             lines.append(f"{phase:>8}: {_fmt_ms(h.summary())}")
+    for name, h in sorted(reg.histograms.items()):
+        if name.startswith(PHASE_PREFIX):
+            s = h.summary()
+            lines.append(f"{name:>20}: n={s['count']} "
+                         f"p50 {s['p50'] * 1e3:.3f} / "
+                         f"p95 {s['p95'] * 1e3:.3f} ms, "
+                         f"total {h.total:.3f} s")
     if peaks:
         lines.append("counter peaks: " + ", ".join(
             f"{k} {v:g}" for k, v in sorted(peaks.items())))

@@ -44,18 +44,20 @@ def attn_init(rng, d_model: int, n_heads: int, n_kv_heads: int, head_dim: int):
     }
 
 
+@jax.named_scope("attn_proj")
 def qkv(params, x):
     """x (b,s,d) -> q (b,s,H,hd), k/v (b,s,KH,hd) in x.dtype."""
     dt = x.dtype
-    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(dt))
-    k = jnp.einsum("bsd,dhk->bshk", x, params["wk"].astype(dt))
-    v = jnp.einsum("bsd,dhk->bshk", x, params["wv"].astype(dt))
+    q = jnp.einsum("bsd,dhk->bshk", x, linit.cast(params["wq"], dt))
+    k = jnp.einsum("bsd,dhk->bshk", x, linit.cast(params["wk"], dt))
+    v = jnp.einsum("bsd,dhk->bshk", x, linit.cast(params["wv"], dt))
     return q, k, v
 
 
+@jax.named_scope("attn_proj")
 def out_proj(params, o):
     """o (b,s,H,hd) -> (b,s,d)."""
-    return jnp.einsum("bshk,hkd->bsd", o, params["wo"].astype(o.dtype))
+    return jnp.einsum("bshk,hkd->bsd", o, linit.cast(params["wo"], o.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -314,6 +316,7 @@ def chunk_attention(
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("decode_attention")
 def decode_attention(
     q: jnp.ndarray,        # (b, 1, H, hd)
     k_cache: jnp.ndarray,  # (b, S, KH, hd)
@@ -354,14 +357,16 @@ def decode_attention(
     # leaves behind — serving/resilience.py) can never contaminate the
     # next occupant of a recycled slot or page.  Bit-identical for
     # finite stale rows (their prob is exactly 0 either way).
-    vmask = jnp.arange(S)[None, :, None, None] <= jnp.reshape(
-        cur, (-1, 1, 1, 1) if cur.ndim else ())
-    o = jnp.einsum("bkgt,btkd->bkgd", probs,
-                   jnp.where(vmask, kv_dequantize(v_cache), 0.0))
+    with jax.named_scope("kv_mask"):
+        vmask = jnp.arange(S)[None, :, None, None] <= jnp.reshape(
+            cur, (-1, 1, 1, 1) if cur.ndim else ())
+        v_masked = jnp.where(vmask, kv_dequantize(v_cache), 0.0)
+    o = jnp.einsum("bkgt,btkd->bkgd", probs, v_masked)
     o = constrain(o, "dp", None, None, "model")  # back on the cache layout
     return o.reshape(b, 1, h, hd).astype(q.dtype)
 
 
+@jax.named_scope("kv_write")
 def cache_update(
     k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     k_new: jnp.ndarray, v_new: jnp.ndarray, cur_index: jnp.ndarray,
@@ -406,6 +411,7 @@ def cache_update(
 # all-zero table rows and cur = 0, so their stale tick writes land there.
 
 
+@jax.named_scope("kv_write")
 def paged_cache_update(
     k_arena: jnp.ndarray,  # (P, page_size, KH, hd)
     v_arena: jnp.ndarray,
@@ -424,6 +430,7 @@ def paged_cache_update(
             v_arena.at[pid, off].set(kv_cast(v_new[:, 0], v_arena.dtype)))
 
 
+@jax.named_scope("gather_pages")
 def gather_pages(arena: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
     """(P, page_size, KH, hd) x (b, n) block table -> dense (b, n*ps, KH, hd)
     per-slot view for ``decode_attention``."""

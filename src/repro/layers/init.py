@@ -1,4 +1,5 @@
-"""Parameter initialization helpers (no flax — plain pytrees)."""
+"""Parameter helpers (no flax — plain pytrees): initialization, and the
+cast of a stored weight to the compute dtype."""
 
 from __future__ import annotations
 
@@ -13,6 +14,13 @@ def trunc_normal(rng, shape, stddev, dtype=jnp.float32):
 def dense_init(rng, fan_in: int, shape, dtype=jnp.float32):
     """Variance-scaling init (stddev = 1/sqrt(fan_in))."""
     return trunc_normal(rng, shape, fan_in ** -0.5, dtype)
+
+
+@jax.named_scope("weights_cast")
+def cast(w, dtype):
+    """``w`` in the compute dtype, under the ``weights_cast`` scope, so
+    the profiler's trace names the cast's device time."""
+    return w.astype(dtype)
 
 
 def stacked(rng, n: int, init_fn):

@@ -19,12 +19,13 @@ def mlp_init(rng, d_model: int, d_ff: int, act: str = "silu"):
     return p
 
 
+@jax.named_scope("mlp")
 def mlp_apply(params, x, *, act: str = "silu"):
     dt = x.dtype
-    h = jnp.einsum("bsd,df->bsf", x, params["w_in"].astype(dt))
+    h = jnp.einsum("bsd,df->bsf", x, linit.cast(params["w_in"], dt))
     if act == "silu":
-        gate = jnp.einsum("bsd,df->bsf", x, params["w_gate"].astype(dt))
+        gate = jnp.einsum("bsd,df->bsf", x, linit.cast(params["w_gate"], dt))
         h = jax.nn.silu(gate) * h
     else:
         h = jax.nn.gelu(h)
-    return jnp.einsum("bsf,fd->bsd", h, params["w_out"].astype(dt))
+    return jnp.einsum("bsf,fd->bsd", h, linit.cast(params["w_out"], dt))

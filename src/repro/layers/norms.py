@@ -8,6 +8,7 @@ policy's — i.e. [4]'s coupled Goldschmidt iteration under ``gs_*`` modes.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.policy import NumericsPolicy
@@ -21,6 +22,7 @@ def layernorm_init(d: int):
     return {"scale": jnp.ones((d,), jnp.float32), "bias": jnp.zeros((d,), jnp.float32)}
 
 
+@jax.named_scope("rmsnorm")
 def rmsnorm(params, x, *, eps: float, policy: NumericsPolicy,
             kernel_impl: str = "jnp"):
     if kernel_impl == "pallas":

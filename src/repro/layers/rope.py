@@ -10,6 +10,7 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 
 
@@ -40,6 +41,7 @@ def mrope_cos_sin(pos_ids: jnp.ndarray, head_dim: int, theta: float,
     return jnp.cos(ang), jnp.sin(ang)
 
 
+@jax.named_scope("rope")
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
     """x (b, s, h, d); cos/sin (b, s, d//2).  Rotate-half convention."""
     d2 = x.shape[-1] // 2

@@ -76,6 +76,7 @@ def _rope_info(cfg: ArchConfig, batch: int, seq: int,
     return None
 
 
+@jax.named_scope("embed")
 def embed_tokens(cfg: ArchConfig, params: Params, tokens: jnp.ndarray,
                  cur_index: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
@@ -93,13 +94,16 @@ def embed_tokens(cfg: ArchConfig, params: Params, tokens: jnp.ndarray,
     return x
 
 
+@jax.named_scope("lm_head")
 def unembed(cfg: ArchConfig, params: Params, x: jnp.ndarray) -> jnp.ndarray:
     h = norm_apply(cfg.norm, params["final_norm"], x, eps=cfg.norm_eps,
                    policy=cfg.policy(), kernel_impl=cfg.kernel_impl)
     if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", h, params["embed"].astype(h.dtype))
+        logits = jnp.einsum("bsd,vd->bsv", h,
+                            linit.cast(params["embed"], h.dtype))
     else:
-        logits = jnp.einsum("bsd,dv->bsv", h, params["lm_head"].astype(h.dtype))
+        logits = jnp.einsum("bsd,dv->bsv", h,
+                            linit.cast(params["lm_head"], h.dtype))
     return constrain(logits, "dp", None, "model")
 
 
@@ -129,7 +133,11 @@ def _stack(cfg: ArchConfig, params: Params, x: jnp.ndarray, *, mode: str,
     xs = (params["layers"], states if consumes_state else None)
     if cfg.scan_layers:
         fn = jax.checkpoint(body) if (cfg.remat and mode == "train") else body
-        x, new_states = jax.lax.scan(fn, x, xs)
+        # the scope names what the scan itself adds: each layer's slice
+        # of the stacked weights and states, and the write of its new
+        # states back into the stack
+        with jax.named_scope("layer_scan"):
+            x, new_states = jax.lax.scan(fn, x, xs)
     else:
         outs = []
         for gi in range(cfg.n_groups):

@@ -12,8 +12,10 @@ already holds).
 * :mod:`repro.obs.trace` — :class:`Tracer`, a ring-buffered span/event
   recorder with an injectable monotonic clock (the engine binds its own
   skew-adjusted clock, so the chaos harness's clock-skew faults move
-  the trace timeline the way they move deadlines).
-* :mod:`repro.obs.metrics` — counter / gauge / histogram registry with
+  the trace timeline the way they move deadlines).  ``Tracer.phase``
+  spans the engine's host phases and mirrors each into the profiler's
+  trace as an ``engine.<phase>`` annotation, on the device trace's clock.
+* :mod:`repro.obs.metrics` — histogram registry with
   p50/p95/p99 summaries; :func:`summarize` backs the real TTFT and
   inter-token-latency distributions on ``ServeMetrics``.
 * :mod:`repro.obs.export` — JSONL event log plus Chrome-trace/Perfetto
@@ -29,13 +31,13 @@ from repro.obs.export import (load_events, request_chains,  # noqa: F401
                               to_chrome_trace, validate_chains,
                               validate_chrome_trace, write_chrome_trace,
                               write_jsonl)
-from repro.obs.metrics import (Counter, Gauge, Histogram,  # noqa: F401
-                               MetricsRegistry, percentile, summarize)
+from repro.obs.metrics import (Histogram, MetricsRegistry,  # noqa: F401
+                               percentile, summarize)
 from repro.obs.trace import ENGINE_TRACK, POOL_TRACK, Tracer  # noqa: F401
 
 __all__ = [
     "Tracer", "ENGINE_TRACK", "POOL_TRACK",
-    "Counter", "Gauge", "Histogram", "MetricsRegistry",
+    "Histogram", "MetricsRegistry",
     "percentile", "summarize",
     "to_chrome_trace", "write_chrome_trace", "write_jsonl", "load_events",
     "request_chains", "validate_chains", "validate_chrome_trace",

@@ -1,12 +1,12 @@
-"""Counter / gauge / histogram registry with p50/p95/p99 summaries.
+"""Histogram registry with p50/p95/p99 summaries.
 
 :func:`summarize` is the workhorse: it turns a flat sample list into
 the ``{count, mean, min, max, p50, p95, p99}`` dict that
 ``ServeMetrics.to_dict`` embeds for TTFT and inter-token latency (the
-real distributions the flat aggregate used to hide).  The class layer
-(:class:`Histogram` with a bounded deterministic reservoir,
-:class:`Counter`, :class:`Gauge`, :class:`MetricsRegistry`) is the
-accumulation surface ``obsview`` and future instrumentation build on.
+real distributions the flat aggregate used to hide).
+:class:`Histogram` (a bounded deterministic reservoir) and
+:class:`MetricsRegistry` are what ``obsview`` accumulates span
+durations in.
 
 Percentiles use linear interpolation between order statistics (the
 numpy ``linear`` method), computed in pure Python so the hot path never
@@ -56,32 +56,6 @@ def summarize(values: Sequence[float],
         key = f"p{q:g}".replace(".", "_")
         out[key] = percentile(vs, q) if n else 0.0
     return out
-
-
-class Counter:
-    """Monotonic event count."""
-
-    __slots__ = ("value",)
-
-    def __init__(self) -> None:
-        self.value = 0
-
-    def inc(self, n: int = 1) -> None:
-        if n < 0:
-            raise ValueError(f"counter increments must be >= 0, got {n}")
-        self.value += n
-
-
-class Gauge:
-    """Last-write-wins level (queue depth, pages in use)."""
-
-    __slots__ = ("value",)
-
-    def __init__(self) -> None:
-        self.value = 0.0
-
-    def set(self, v: float) -> None:
-        self.value = float(v)
 
 
 class Histogram:
@@ -146,26 +120,12 @@ class Histogram:
 
 @dataclasses.dataclass
 class MetricsRegistry:
-    """Name-keyed get-or-create registry of the three instrument kinds;
-    ``to_dict`` snapshots everything JSON-serializably."""
+    """Name-keyed get-or-create registry of histograms; ``to_dict``
+    snapshots them JSON-serializably."""
 
-    counters: Dict[str, Counter] = dataclasses.field(default_factory=dict)
-    gauges: Dict[str, Gauge] = dataclasses.field(default_factory=dict)
     histograms: Dict[str, Histogram] = dataclasses.field(
         default_factory=dict)
     histogram_capacity: int = 8192
-
-    def counter(self, name: str) -> Counter:
-        c = self.counters.get(name)
-        if c is None:
-            c = self.counters[name] = Counter()
-        return c
-
-    def gauge(self, name: str) -> Gauge:
-        g = self.gauges.get(name)
-        if g is None:
-            g = self.gauges[name] = Gauge()
-        return g
 
     def histogram(self, name: str,
                   capacity: Optional[int] = None) -> Histogram:
@@ -177,8 +137,6 @@ class MetricsRegistry:
 
     def to_dict(self) -> dict:
         return {
-            "counters": {k: c.value for k, c in sorted(self.counters.items())},
-            "gauges": {k: g.value for k, g in sorted(self.gauges.items())},
             "histograms": {k: h.summary()
                            for k, h in sorted(self.histograms.items())},
         }

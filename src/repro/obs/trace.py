@@ -1,6 +1,6 @@
 """Ring-buffered span/event recorder with an injectable monotonic clock.
 
-Design constraints (the ≤5% tracing-overhead CI gate is real):
+Design constraints (``tests/test_obs.py`` counts them on every run):
 
 * **Host-side only** — every record is built from values the caller
   already holds (slot ids, rids, counts); recording never touches a
@@ -22,6 +22,13 @@ Event forms (``kind`` first; ``track`` is ``(group, index)``, e.g.
 * ``("inst", name, track, t, args)`` — a point event.
 * ``("ctr", name, track, t, value)`` — a counter sample.
 
+``phase(name, **args)`` is a context manager for the engine's host
+phases: it records ``engine.<name>`` as a span on ``ENGINE_TRACK`` and
+opens a ``jax.profiler.TraceAnnotation`` of the same name, so the same
+interval also lands on the profiler's host plane, on the device trace's
+clock (a no-op while no profiler session runs).  The span closes on every
+exit path, exceptions included.
+
 ``begin``/``end`` pair open intervals by ``(track, name)`` — ``end``
 on a never-begun pair is a no-op (returns ``None``), which lets the
 engine close "whichever of queued/decode is open" unconditionally on
@@ -35,6 +42,8 @@ import time
 from collections import deque
 from typing import Any, Dict, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 Track = Tuple[str, int]
 
 ENGINE_TRACK: Track = ("engine", 0)
@@ -43,6 +52,8 @@ POOL_TRACK: Track = ("pool", 0)
 SPAN = "span"
 INSTANT = "inst"
 COUNTER = "ctr"
+
+PHASE_PREFIX = "engine."
 
 
 class Tracer:
@@ -106,6 +117,10 @@ class Tracer:
         self._push((SPAN, name, track, t0, t1 - t0, args or None))
         return t1 - t0
 
+    def phase(self, name: str, **args: Any) -> "_Phase":
+        """``with tracer.phase("admit", tick=3):`` -- see module docstring."""
+        return _Phase(self, PHASE_PREFIX + name, args or None)
+
     def instant(self, name: str, track: Track = ENGINE_TRACK,
                 t: Optional[float] = None, **args: Any) -> None:
         if t is None:
@@ -135,3 +150,28 @@ class Tracer:
         self.events.clear()
         self._open.clear()
         self.dropped = 0
+
+
+class _Phase:
+    """One host phase: a span on the engine clock and a profiler
+    annotation over the same interval."""
+
+    __slots__ = ("tracer", "name", "args", "t0", "ann")
+
+    def __init__(self, tracer: Tracer, name: str, args: Optional[dict]):
+        self.tracer = tracer
+        self.name = name
+        self.args = args
+
+    def __enter__(self) -> "_Phase":
+        self.ann = TraceAnnotation(self.name)
+        self.ann.__enter__()
+        self.t0 = self.tracer._clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        tr = self.tracer
+        t0 = self.t0
+        tr._push((SPAN, self.name, ENGINE_TRACK, t0, tr._clock() - t0,
+                  self.args))
+        self.ann.__exit__(*exc)

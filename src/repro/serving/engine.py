@@ -119,6 +119,7 @@ table):
 from __future__ import annotations
 
 import bisect
+import contextlib
 import dataclasses
 import time
 from collections import deque
@@ -152,6 +153,10 @@ from repro.serving.sampler import sample_tokens
 
 SCHEDULERS = ("continuous", "static")
 POOLS = ("slot", "paged")
+
+# what a host phase costs with no tracer attached: one shared, reusable
+# context manager that does nothing (no allocation per phase)
+_NO_PHASE = contextlib.nullcontext()
 
 
 def prefill_batch(cfg: ArchConfig, req: Request) -> dict:
@@ -441,10 +446,13 @@ class Engine:
                 # -1 (token ids are always >= 0): the guarded tick keeps
                 # a single (n_slots,) output, so the guard costs one
                 # vocab-width isfinite reduce + a where — no second
-                # device->host transfer, same out_sharding as unguarded
-                valid = jnp.all(
-                    jnp.isfinite(logits[:, -1, :].astype(jnp.float32)),
-                    axis=-1)
+                # device->host transfer, same out_sharding as unguarded.
+                # XLA fuses the reduce into the head's matmul, so it
+                # shares the head's scope
+                with jax.named_scope("lm_head"):
+                    valid = jnp.all(
+                        jnp.isfinite(logits[:, -1, :].astype(jnp.float32)),
+                        axis=-1)
                 return jnp.where(valid, toks, -1)
 
             if paged:
@@ -578,9 +586,10 @@ class Engine:
                                        - len(st.tokens) + 1),
                        sampling=req.sampling, frames=req.frames)
 
-    def _do_prefill(self, st: RequestState, pool: CachePool,
+    def _do_prefill(self, st: RequestState, eff: Request, pool: CachePool,
                     metrics: ServeMetrics, clock) -> bool:
-        """Admit ``st`` into a slot.  Returns False when the request was
+        """Admit ``st`` into a slot as ``eff`` (its
+        :meth:`_effective_request`).  Returns False when the request was
         failed instead (non-finite prefill logits under the numeric
         guard) — the slot is already released.
 
@@ -598,7 +607,6 @@ class Engine:
         tr = self.ecfg.tracer
         tc0 = clock() if tr is not None else 0.0
         replay = len(st.tokens) > 0
-        eff = self._effective_request(st)
         t0 = time.perf_counter()
         # alloc first: a paged pool resolves prefix hits here, and a
         # whole-prompt hit means the prefill never runs at all
@@ -697,6 +705,13 @@ class Engine:
         until the clock passes it (the loop sleeps when idle).
         Admission is FIFO: a head-of-line request the pool cannot fit
         yet waits for active slots to drain (page budget included).
+
+        With a tracer attached, the loop's host phases (``admit``,
+        ``prefill``, ``page_append``, ``tick_prepare``, ``tick_dispatch``,
+        ``tick_wait``, ``emit``, ``idle``, all inside ``run``) are
+        recorded as ``engine.<phase>`` spans that carry the decode tick
+        number, and mirrored into the profiler's trace
+        (:meth:`~repro.obs.trace.Tracer.phase`).
         """
         if scheduler not in SCHEDULERS:
             raise ValueError(f"scheduler must be one of {SCHEDULERS}")
@@ -707,8 +722,6 @@ class Engine:
         for req in requests:
             self._validate(req)
         n = self.ecfg.n_slots
-        guard = self.ecfg.numeric_guard
-        inj = self.ecfg.injector
         pool = self._make_pool()
         max_top_k = max((self._effective_k(r) for r in requests), default=0)
         metrics = ServeMetrics(n_requests=len(requests), n_slots=n)
@@ -727,17 +740,64 @@ class Engine:
                        n_slots=n, pool=self.ecfg.pool,
                        n_requests=len(requests))
 
-        states: List[RequestState] = [
-            RequestState(r, t_arrive=r.arrival_time,
-                         deadline_at=(r.arrival_time
-                                      + r.sampling.deadline_ms / 1e3
-                                      if r.sampling.deadline_ms is not None
-                                      else float("inf")))
-            for r in sorted(requests, key=lambda r: (r.arrival_time, r.rid))]
+        # the run phase opens at engine-clock ~0: one offset maps every
+        # span of the run onto the profiler's clock
+        with (tr.phase("run") if tr is not None else _NO_PHASE):
+            states: List[RequestState] = [
+                RequestState(r, t_arrive=r.arrival_time,
+                             deadline_at=(
+                                 r.arrival_time
+                                 + r.sampling.deadline_ms / 1e3
+                                 if r.sampling.deadline_ms is not None
+                                 else float("inf")))
+                for r in sorted(requests,
+                                key=lambda r: (r.arrival_time, r.rid))]
+            if tr is not None:
+                for st in states:
+                    tr.instant("submitted", ("req", st.request.rid),
+                               t=st.t_arrive)
+            self._serve(states, pool, metrics, clock, skew, scheduler,
+                        max_top_k)
+
+        self._cancel_rids.clear()
+        fb_by_kernel = {
+            k: v - fb_start.get(k, 0)
+            for k, v in _dispatch.fallback_stats().items()
+            if v - fb_start.get(k, 0)}
+        metrics.kernel_fallbacks_by_kernel = fb_by_kernel
+        metrics.kernel_fallbacks = sum(fb_by_kernel.values())
+        metrics.dispatch = _dispatch.dispatch_delta(disp_start)
+        metrics.makespan_s = clock()
         if tr is not None:
-            for st in states:
-                tr.instant("submitted", ("req", st.request.rid),
-                           t=st.t_arrive)
+            tr.instant("run_end", ENGINE_TRACK,
+                       decode_ticks=metrics.decode_ticks)
+        stats = pool.stats()
+        metrics.pool = stats
+        metrics.prefix_hits = stats.get("prefix_hits", 0)
+        metrics.prefix_hit_tokens = stats.get("prefix_hit_tokens", 0)
+        outputs = {}
+        for st in states:
+            assert st.status == FINISHED, (st.request.rid, st.status)
+            outputs[st.request.rid] = GenerationResult(
+                rid=st.request.rid,
+                prompt_len=st.request.prompt_len,
+                tokens=np.asarray(st.tokens, np.int32),
+                ttft_s=st.ttft if st.tokens else 0.0,
+                finish_s=st.t_finish - st.t_arrive,
+                finish_reason=st.finish_reason,
+                metrics=metrics,
+            )
+        return ServeResult(outputs, metrics)
+
+    def _serve(self, states: List[RequestState], pool: CachePool,
+               metrics: ServeMetrics, clock, skew: List[float],
+               scheduler: str, max_top_k: int) -> None:
+        """:meth:`run`'s loop: admission, page appends and decode ticks
+        until every state has finished."""
+        n = self.ecfg.n_slots
+        guard = self.ecfg.numeric_guard
+        inj = self.ecfg.injector
+        tr = self.ecfg.tracer
         # deques: the admission loop pops from the head every tick, and a
         # list.pop(0) there is O(n) — quadratic over a long Poisson trace
         pending: Deque[RequestState] = deque(states)
@@ -848,7 +908,12 @@ class Engine:
         def start(st: RequestState):
             if tr is not None:
                 tr.end("queued", ("req", st.request.rid))
-            if not self._do_prefill(st, pool, metrics, clock):
+            eff = self._effective_request(st)
+            with (tr.phase("prefill", tick=tick_no, rid=st.request.rid,
+                           prompt_len=eff.prompt_len)
+                  if tr is not None else _NO_PHASE):
+                ok = self._do_prefill(st, eff, pool, metrics, clock)
+            if not ok:
                 return  # failed at prefill (numeric guard); slot released
             st.admit_seq = admit_seq[0]
             admit_seq[0] += 1
@@ -906,34 +971,37 @@ class Engine:
                     if self._paged and ev.get("release"):
                         pool.release_pages()
                     poison_queue.update(ev.get("poison", ()))
-            admit_arrivals()
-            apply_cancels()
-            expire_deadlines()
-            admitted = 0
-            if scheduler == "continuous":
-                budget = self.ecfg.max_prefill_per_tick
-                while (ready and budget > 0
-                       and pool.can_admit(self._effective_request(ready[0]))):
-                    start(ready.popleft())
-                    budget -= 1
-                    admitted += 1
-            else:  # static lockstep: full group in, nothing until group out
-                if not active and ready:
-                    while ready and pool.can_admit(
-                            self._effective_request(ready[0])):
+            with (tr.phase("admit", tick=tick_no) if tr is not None
+                  else _NO_PHASE):
+                admit_arrivals()
+                apply_cancels()
+                expire_deadlines()
+                admitted = 0
+                if scheduler == "continuous":
+                    budget = self.ecfg.max_prefill_per_tick
+                    while (ready and budget > 0
+                           and pool.can_admit(
+                               self._effective_request(ready[0]))):
                         start(ready.popleft())
+                        budget -= 1
                         admitted += 1
+                else:  # static lockstep: full group in, nothing until out
+                    if not active and ready:
+                        while ready and pool.can_admit(
+                                self._effective_request(ready[0])):
+                            start(ready.popleft())
+                            admitted += 1
 
-            head_stuck = (ready and not admitted
-                          and not pool.can_admit(
-                              self._effective_request(ready[0])))
-            stall = stall + 1 if (head_stuck and active
-                                  and scheduler == "continuous") else 0
-            if (self._paged and active
-                    and stall >= self.ecfg.preempt_after_ticks):
-                preempt_youngest()
-                stall = 0
-                continue  # retry admission before burning a tick
+                head_stuck = (ready and not admitted
+                              and not pool.can_admit(
+                                  self._effective_request(ready[0])))
+                stall = stall + 1 if (head_stuck and active
+                                      and scheduler == "continuous") else 0
+                if (self._paged and active
+                        and stall >= self.ecfg.preempt_after_ticks):
+                    preempt_youngest()
+                    stall = 0
+                    continue  # retry admission before burning a tick
 
             if not active:
                 if ready and not pending and not admitted:
@@ -951,8 +1019,10 @@ class Engine:
                              pool.pages_needed(self._effective_request(s))
                              for s in ready} if self._paged else None))
                 if pending:  # idle until the next arrival
-                    time.sleep(max(0.0, min(
-                        pending[0].t_arrive - clock(), 0.005)))
+                    with (tr.phase("idle", tick=tick_no) if tr is not None
+                          else _NO_PHASE):
+                        time.sleep(max(0.0, min(
+                            pending[0].t_arrive - clock(), 0.005)))
                 continue
 
             if self._paged:
@@ -966,67 +1036,73 @@ class Engine:
                 # freed pages would re-admit the preempted request first
                 # and the blocked slot would never reach the tick below
                 # (live-lock).
-                for slot in sorted(active,
-                                   key=lambda s: active[s].admit_seq):
-                    while (slot in active
-                           and not pool.ensure_page(slot, int(cur[slot]))):
-                        if len(active) > 1:
-                            preempt_youngest()  # may preempt `slot` itself
-                            continue
-                        st = active[slot]
-                        if tr is not None:
-                            tr.instant("admission_error", ENGINE_TRACK,
-                                       rid=st.request.rid)
-                        raise AdmissionError(
-                            st.request.rid, pool.stats(),
-                            queued=[s.request.rid for s in ready],
-                            pages_needed={st.request.rid: 1})
+                with (tr.phase("page_append", tick=tick_no)
+                      if tr is not None else _NO_PHASE):
+                    for slot in sorted(active,
+                                       key=lambda s: active[s].admit_seq):
+                        while (slot in active and not pool.ensure_page(
+                                slot, int(cur[slot]))):
+                            if len(active) > 1:
+                                preempt_youngest()  # may preempt `slot`
+                                continue
+                            st = active[slot]
+                            if tr is not None:
+                                tr.instant("admission_error", ENGINE_TRACK,
+                                           rid=st.request.rid)
+                            raise AdmissionError(
+                                st.request.rid, pool.stats(),
+                                queued=[s.request.rid for s in ready],
+                                pages_needed={st.request.rid: 1})
 
-            if poison_queue:
-                by_rid = {st.request.rid: slot
-                          for slot, st in active.items()}
-                for rid in sorted(poison_queue):
-                    if rid in by_rid:
-                        poison_slot_cache(pool, by_rid[rid])
-                        poison_queue.discard(rid)
-                        if tr is not None:
-                            tr.instant("poison", ("slot", by_rid[rid]),
-                                       rid=rid)
+            with (tr.phase("tick_prepare", tick=tick_no) if tr is not None
+                  else _NO_PHASE):
+                if poison_queue:
+                    by_rid = {st.request.rid: slot
+                              for slot, st in active.items()}
+                    for rid in sorted(poison_queue):
+                        if rid in by_rid:
+                            poison_slot_cache(pool, by_rid[rid])
+                            poison_queue.discard(rid)
+                            if tr is not None:
+                                tr.instant("poison", ("slot", by_rid[rid]),
+                                           rid=rid)
 
-            stochastic = bool(np.any(temps[list(active)] > 0))
-            tick = self._tick_fn(stochastic, max_top_k, guard)
-            operands = (jnp.asarray(cur), jnp.asarray(last_tok[:, None]),
-                        jnp.asarray(temps), jnp.asarray(topks),
-                        jnp.asarray(rids), self._key)
+                stochastic = bool(np.any(temps[list(active)] > 0))
+                tick = self._tick_fn(stochastic, max_top_k, guard)
+                # paged ticks take the block table as one more operand
+                table = (jnp.asarray(pool.table),) if self._paged else ()
+                operands = table + (
+                    jnp.asarray(cur), jnp.asarray(last_tok[:, None]),
+                    jnp.asarray(temps), jnp.asarray(topks),
+                    jnp.asarray(rids), self._key)
             attempts = 0
             t_tick0 = clock() if tr is not None else 0.0
             t0 = time.perf_counter()
-            while True:
-                try:
-                    if inj is not None and inj.take_failure(tick_no):
-                        raise TickFailure(
-                            f"injected tick failure at tick {tick_no}")
-                    if self._paged:
-                        out, pool.cache = tick(self.params, pool.cache,
-                                               jnp.asarray(pool.table),
-                                               *operands)
-                    else:
+            with (tr.phase("tick_dispatch", tick=tick_no) if tr is not None
+                  else _NO_PHASE):
+                while True:
+                    try:
+                        if inj is not None and inj.take_failure(tick_no):
+                            raise TickFailure(
+                                f"injected tick failure at tick {tick_no}")
                         out, pool.cache = tick(self.params, pool.cache,
                                                *operands)
-                    break
-                except TickFailure:
-                    # transient device error: retry the identical tick
-                    # (the injected raise precedes the call, so the
-                    # donated cache was never consumed)
-                    if attempts >= self.ecfg.max_retries:
-                        raise
-                    attempts += 1
-                    metrics.retried += 1
-                    if tr is not None:
-                        tr.instant("tick_retry", ENGINE_TRACK,
-                                   tick=tick_no, attempt=attempts)
-                    time.sleep(self.ecfg.retry_backoff_s)
-            nxt = np.asarray(jax.block_until_ready(out))
+                        break
+                    except TickFailure:
+                        # transient device error: retry the identical
+                        # tick (the injected raise precedes the call, so
+                        # the donated cache was never consumed)
+                        if attempts >= self.ecfg.max_retries:
+                            raise
+                        attempts += 1
+                        metrics.retried += 1
+                        if tr is not None:
+                            tr.instant("tick_retry", ENGINE_TRACK,
+                                       tick=tick_no, attempt=attempts)
+                        time.sleep(self.ecfg.retry_backoff_s)
+            with (tr.phase("tick_wait", tick=tick_no) if tr is not None
+                  else _NO_PHASE):
+                nxt = np.asarray(jax.block_until_ready(out))
             # guarded ticks encode a tripped slot as sentinel token -1
             valid = (nxt >= 0) if guard else None
             metrics.decode_time_s += time.perf_counter() - t0
@@ -1040,67 +1116,41 @@ class Engine:
                 tr.counter("active_slots", len(active), t=t_now)
                 tr.counter("ready_queue", len(ready), t=t_now)
 
-            if valid is not None:
-                # quarantine: fail poisoned slots NOW — their garbage
-                # token is never appended, their (masked, soon to be
-                # recycled) cache rows free this tick
+            with (tr.phase("emit", tick=tick_no) if tr is not None
+                  else _NO_PHASE):
+                if valid is not None:
+                    # quarantine: fail poisoned slots NOW — their garbage
+                    # token is never appended, their (masked, soon to be
+                    # recycled) cache rows free this tick
+                    for slot in list(active):
+                        if not valid[slot]:
+                            if tr is not None:
+                                tr.instant(
+                                    "quarantine",
+                                    ("req", active[slot].request.rid),
+                                    slot=slot, where="decode")
+                            evict(slot, FINISH_NUMERIC)
+                            metrics.failed += 1
+                metrics.decode_tokens += len(active)
+
+                now = clock()
                 for slot in list(active):
-                    if not valid[slot]:
-                        if tr is not None:
-                            tr.instant("quarantine",
-                                       ("req", active[slot].request.rid),
-                                       slot=slot, where="decode")
-                        evict(slot, FINISH_NUMERIC)
-                        metrics.failed += 1
-            metrics.decode_tokens += len(active)
-
-            now = clock()
-            for slot in list(active):
-                st = active[slot]
-                st.tokens.append(int(nxt[slot]))
-                metrics.itl_samples.append(now - st.t_last_token)
-                st.t_last_token = now
-                if st.done:
-                    # Under 'static' the freed slot stays unused (and its
-                    # lane keeps burning in every tick) until the whole
-                    # group drains — admission is gated on `not active`.
-                    evict(slot, None)
-                elif now > st.deadline_at:
-                    evict(slot, FINISH_DEADLINE)
-                    metrics.timed_out += 1
-                else:
-                    cur[slot] = st.cur_index
-                    last_tok[slot] = st.tokens[-1]
-
-        self._cancel_rids.clear()
-        fb_by_kernel = {
-            k: v - fb_start.get(k, 0)
-            for k, v in _dispatch.fallback_stats().items()
-            if v - fb_start.get(k, 0)}
-        metrics.kernel_fallbacks_by_kernel = fb_by_kernel
-        metrics.kernel_fallbacks = sum(fb_by_kernel.values())
-        metrics.dispatch = _dispatch.dispatch_delta(disp_start)
-        metrics.makespan_s = clock()
-        if tr is not None:
-            tr.instant("run_end", ENGINE_TRACK,
-                       decode_ticks=metrics.decode_ticks)
-        stats = pool.stats()
-        metrics.pool = stats
-        metrics.prefix_hits = stats.get("prefix_hits", 0)
-        metrics.prefix_hit_tokens = stats.get("prefix_hit_tokens", 0)
-        outputs = {}
-        for st in states:
-            assert st.status == FINISHED, (st.request.rid, st.status)
-            outputs[st.request.rid] = GenerationResult(
-                rid=st.request.rid,
-                prompt_len=st.request.prompt_len,
-                tokens=np.asarray(st.tokens, np.int32),
-                ttft_s=st.ttft if st.tokens else 0.0,
-                finish_s=st.t_finish - st.t_arrive,
-                finish_reason=st.finish_reason,
-                metrics=metrics,
-            )
-        return ServeResult(outputs, metrics)
+                    st = active[slot]
+                    st.tokens.append(int(nxt[slot]))
+                    metrics.itl_samples.append(now - st.t_last_token)
+                    st.t_last_token = now
+                    if st.done:
+                        # Under 'static' the freed slot stays unused (and
+                        # its lane keeps burning in every tick) until the
+                        # whole group drains — admission is gated on
+                        # `not active`.
+                        evict(slot, None)
+                    elif now > st.deadline_at:
+                        evict(slot, FINISH_DEADLINE)
+                        metrics.timed_out += 1
+                    else:
+                        cur[slot] = st.cur_index
+                        last_tok[slot] = st.tokens[-1]
 
     def warmup(self, prompt_lens: Sequence[int], *,
                stochastic: bool = False) -> None:

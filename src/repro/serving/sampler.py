@@ -41,6 +41,7 @@ from repro.core.policy import NumericsPolicy
 from repro.layers.attention import NEG_INF  # the shared masking constant
 
 
+@jax.named_scope("sampler")
 def sample_tokens(
     logits: jnp.ndarray,  # (b, V) last-position logits
     *,

@@ -4,10 +4,13 @@ The contract under test is the obs-smoke CI gate: every request served
 through a traced engine — including every chaos fault class — leaves a
 complete lifecycle span chain whose finish instant matches the engine's
 reported finish reason; the exported Chrome trace is structurally valid;
-and tracing costs <= 5% per decode tick over the untraced engine.
+every decode pass records its host phases in order; and tracing costs a
+bounded number of host-side records per tick (its time is measured on the
+chip, by the benchmark).
 """
 
 import json
+import re
 import subprocess
 import sys
 import os
@@ -20,11 +23,11 @@ from repro import configs
 from repro.kernels import ops
 from repro.kernels.tuning import dispatch
 from repro.models import api
-from repro.obs import (ENGINE_TRACK, Counter, Gauge, Histogram,
-                       MetricsRegistry, Tracer, load_events, percentile,
-                       request_chains, summarize, to_chrome_trace,
-                       validate_chains, validate_chrome_trace,
-                       write_chrome_trace, write_jsonl)
+from repro.obs import (ENGINE_TRACK, Histogram, MetricsRegistry, Tracer,
+                       load_events, percentile, request_chains, summarize,
+                       to_chrome_trace, validate_chains,
+                       validate_chrome_trace, write_chrome_trace,
+                       write_jsonl)
 from repro.serving import (Engine, EngineConfig, FINISH_CANCELLED,
                            FINISH_DEADLINE, FINISH_LENGTH, FINISH_NUMERIC,
                            FINISH_REJECTED, Request, SamplingParams,
@@ -84,16 +87,6 @@ class TestPercentile:
 
 
 class TestInstruments:
-    def test_counter_gauge(self):
-        c, g = Counter(), Gauge()
-        c.inc()
-        c.inc(4)
-        assert c.value == 5
-        with pytest.raises(ValueError):
-            c.inc(-1)
-        g.set(7)
-        assert g.value == 7.0
-
     def test_histogram_exact_below_capacity(self):
         h = Histogram(capacity=64)
         for v in range(10):
@@ -120,13 +113,9 @@ class TestInstruments:
 
     def test_registry_get_or_create_and_dict(self):
         reg = MetricsRegistry()
-        assert reg.counter("x") is reg.counter("x")
-        reg.counter("x").inc(2)
-        reg.gauge("d").set(3)
+        assert reg.histogram("h") is reg.histogram("h")
         reg.histogram("h").observe(1.5)
         d = reg.to_dict()
-        assert d["counters"] == {"x": 2}
-        assert d["gauges"] == {"d": 3.0}
         assert d["histograms"]["h"]["count"] == 1
         json.dumps(d)  # snapshot must be JSON-clean
 
@@ -399,6 +388,11 @@ class TestChaosChains:
         inj = ServeFaultInjector(skew={3: 100.0})
         eng = Engine(cfg, params,
                      EngineConfig(n_slots=2, injector=inj, tracer=tr))
+        # compile first: the 5 s deadline is for serving, and compiling
+        # on a loaded machine can outlast it before tick 3's skew lands
+        # (the warm-up's single tick leaves tick 3's event unconsumed)
+        eng.warmup([6, 5])
+        tr.clear()
         reqs = _requests(cfg, np.random.RandomState(2),
                          [(6, 8, 0.0), (5, 8, 0.0)], deadline_ms=5000.0)
         outs, _ = eng.run(reqs)
@@ -426,28 +420,166 @@ class TestChaosChains:
         assert validate_chains(tr, expect) == []
 
 
+# engine-track records of one decode pass: the admit, page_append,
+# tick_prepare, tick_dispatch, tick_wait and emit phases, the tick span
+# and the active_slots / ready_queue counters
+ENGINE_EVENTS_PER_TICK = 9
+# one request's records: submitted, queued, prefill, first_token, decode
+# and finish on its track, resident on its slot's, and its prefill phase
+# on the engine's
+EVENTS_PER_REQUEST = 8
+TICK_PHASES = ("engine.tick_prepare", "engine.tick_dispatch",
+               "engine.tick_wait", "engine.emit")
+
+
+def _host_values(ev):
+    """Every value an event holds, its args' values included."""
+    vals = list(ev[2]) + list(ev[3:5])
+    args = ev[5] if ev[0] == "span" else (ev[4] if ev[0] == "inst"
+                                          else None)
+    if isinstance(args, dict):
+        vals += list(args.values())
+    return vals
+
+
 class TestTracingOverhead:
     def test_tick_cost_within_budget(self, model):
-        """Min-of-interleaved-repeats pooled tick cost: tracing on vs
-        off, same engines, same trace (the bench obs leg's gate)."""
+        """Tracing's cost, counted: a fixed number of records per decode
+        tick and per request, none holding a device array (so no extra
+        device->host transfer), and the same tokens as an untraced run.
+        Its time is measured on the chip (PERF.md), not here."""
         cfg, params = model
-        rng = np.random.RandomState(0)
-        specs = [(8, 16, 0.0), (6, 16, 0.0), (7, 16, 0.001),
-                 (5, 16, 0.002)]
+        specs = [(8, 16, 0.0), (6, 16, 0.0), (7, 16, 0.0), (5, 16, 0.0)]
+        # pages for every request's whole life and preemption off (it
+        # fires whenever every slot is busy, PERF.md open question 2):
+        # no appends, no replays
+        ecfg = dict(n_slots=2, pool="paged", page_size=4, n_pages=64,
+                    page_reserve="worst", preempt_after_ticks=10 ** 9)
+        tr, outs, m = _traced_run(cfg, params, specs, seed=4, **ecfg)
+        eng = Engine(cfg, params, EngineConfig(**ecfg))
+        outs0, _ = eng.run(_requests(cfg, np.random.RandomState(4), specs))
+        for rid in outs0.keys():
+            np.testing.assert_array_equal(outs0[rid].tokens,
+                                          outs[rid].tokens)
+        events = list(tr.events)
+        # + run, run_start, run_end
+        assert len(events) <= (ENGINE_EVENTS_PER_TICK * m.decode_ticks
+                               + EVENTS_PER_REQUEST * len(specs) + 3)
+        for ev in events:
+            for v in _host_values(ev):
+                assert not isinstance(v, (jax.Array, np.ndarray)), ev
+
+    def test_untraced_run_records_nothing(self, model, monkeypatch):
+        from repro.obs import trace as trace_mod
+
+        def refuse(*a, **k):
+            raise AssertionError("an untraced run recorded an event")
+
+        monkeypatch.setattr(trace_mod.Tracer, "_push", refuse)
+        monkeypatch.setattr(trace_mod, "TraceAnnotation", refuse)
+        cfg, params = model
+        eng = Engine(cfg, params, EngineConfig(n_slots=2, pool="paged",
+                                               page_size=4, n_pages=24))
+        outs, m = eng.run(_requests(cfg, np.random.RandomState(5),
+                                    [(6, 4, 0.0), (5, 3, 0.01)]))
+        assert m.decode_ticks > 0
+
+
+# -- host phases ------------------------------------------------------------
+
+
+class TestEnginePhases:
+    def test_every_decode_pass_records_its_phases_in_order(self, model):
+        cfg, params = model
+        tr, outs, m = _traced_run(
+            cfg, params, [(6, 5, 0.0), (9, 8, 0.0), (4, 3, 0.02)],
+            n_slots=2, pool="paged", page_size=4, n_pages=24)
+        assert not tr.open_spans()
+        phases = [e for e in tr.events
+                  if e[0] == "span" and e[1].startswith("engine.")]
+        by_tick = {}
+        for e in phases:
+            if e[1] in TICK_PHASES:
+                by_tick.setdefault(e[5]["tick"], []).append(e)
+        assert sorted(by_tick) == list(range(m.decode_ticks))
+        for tick, evs in by_tick.items():
+            assert tuple(e[1] for e in evs) == TICK_PHASES, tick
+            for a, b in zip(evs, evs[1:]):
+                assert a[4] >= 0 and a[3] + a[4] <= b[3], (a, b)
+        # every pass's other phases carry its tick too; one run phase
+        # holds them all, from engine-clock ~0
+        run = [e for e in phases if e[1] == "engine.run"]
+        assert len(run) == 1 and run[0][3] < 0.5
+        end = run[0][3] + run[0][4]
+        for e in phases:
+            if e[1] != "engine.run":
+                assert 0 <= e[5]["tick"] <= m.decode_ticks, e
+                assert run[0][3] <= e[3] and e[3] + e[4] <= end, e
+        prefills = [e for e in phases if e[1] == "engine.prefill"]
+        assert sorted(e[5]["rid"] for e in prefills) == [0, 1, 2]
+        assert {e[5]["rid"]: e[5]["prompt_len"] for e in prefills} == \
+            {0: 6, 1: 9, 2: 4}
+        # the request span keeps its name and args beside the phase
+        assert len([e for e in tr.events if e[0] == "span"
+                    and e[1] == "prefill"]) == 3
+
+    def test_phase_lands_in_the_profiler_trace(self, tmp_path):
         tr = Tracer()
-        engines = {
-            "on": Engine(cfg, params, EngineConfig(n_slots=2, tracer=tr)),
-            "off": Engine(cfg, params, EngineConfig(n_slots=2)),
-        }
-        for e in engines.values():
-            e.warmup(sorted({s for s, _, _ in specs}))
-        cost = {"on": [], "off": []}
-        for _ in range(6):
-            for name, e in engines.items():
-                _, m = e.run(_requests(cfg, rng, specs))
-                cost[name].append(m.decode_time_s / max(m.decode_ticks, 1))
-        ratio = min(cost["on"]) / max(min(cost["off"]), 1e-12)
-        assert ratio <= 1.05, f"tracing overhead {ratio:.3f}x > 1.05x"
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tr.phase("admit", tick=7):
+                with tr.phase("prefill", tick=7, rid=3):
+                    pass
+        finally:
+            jax.profiler.stop_trace()
+        xplane = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+        pd = jax.profiler.ProfileData.from_file(str(xplane))
+        host = {}
+        for plane in pd.planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    for e in line.events:
+                        host[e.name] = (e.start_ns, e.duration_ns)
+        assert {"engine.admit", "engine.prefill"} <= set(host)
+        a, p = host["engine.admit"], host["engine.prefill"]
+        assert a[0] <= p[0] and p[0] + p[1] <= a[0] + a[1]
+        spans = {e[1]: e for e in tr.events}
+        assert spans["engine.prefill"][5] == {"tick": 7, "rid": 3}
+        assert spans["engine.admit"][2] == ENGINE_TRACK
+
+
+# scopes the decode tick's program is named by (PERF.md section 3)
+TICK_SCOPES = ("embed", "rmsnorm", "attn_proj", "weights_cast", "rope",
+               "kv_write", "gather_pages", "decode_attention", "kv_mask",
+               "mlp", "layer_scan", "lm_head", "sampler")
+
+
+@pytest.fixture(scope="module")
+def paged_tick_text(model):
+    _, params = model
+    # bfloat16 compute over the fixture's float32 weights: the tick
+    # casts them (lowered only, never run)
+    cfg = configs.get_smoke("tinyllama-1.1b", dtype="bfloat16",
+                            param_dtype="float32")
+    eng = Engine(cfg, params, EngineConfig(n_slots=2, pool="paged",
+                                           page_size=4, n_pages=24))
+    n = 2
+    i32 = lambda *shape: np.zeros(shape, np.int32)  # noqa: E731
+    from repro.serving.cache import make_paged_cache
+
+    cache = make_paged_cache(cfg, n, eng._n_pages, eng.ecfg.page_size,
+                             np.dtype(cfg.dtype))
+    tick = eng._tick_fn(False, 0, eng.ecfg.numeric_guard)
+    return tick.lower(params, cache, i32(n, eng._pages_per_slot), i32(n),
+                      i32(n, 1), np.zeros(n, np.float32), i32(n), i32(n),
+                      jax.random.key(0)).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("scope", TICK_SCOPES)
+def test_paged_tick_holds_named_scope(paged_tick_text, scope):
+    # op locations name their scope path, e.g. "attn_proj/weights_cast/
+    # convert_element_type", relative to the function that holds them
+    assert re.search(rf'(loc\("|/){scope}/', paged_tick_text), scope
 
 
 # -- dispatch counters -------------------------------------------------------
@@ -560,3 +692,10 @@ class TestObsView:
         assert "2 requests" in text
         assert "length 2" in text  # finish reasons
         assert "tick" in text
+        # one line per host phase, with its p50 and p95
+        for phase in ("run", "admit", "prefill", "tick_prepare",
+                      "tick_dispatch", "tick_wait", "emit"):
+            line = [x for x in lines if x.strip().startswith(
+                f"engine.{phase}:")]
+            assert len(line) == 1, phase
+            assert "p50" in line[0] and "p95" in line[0]
