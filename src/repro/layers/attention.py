@@ -368,29 +368,30 @@ def decode_attention(
 
 @jax.named_scope("kv_write")
 def cache_update(
-    k_cache: jnp.ndarray, v_cache: jnp.ndarray,
+    k_stack: jnp.ndarray, v_stack: jnp.ndarray, layer,
     k_new: jnp.ndarray, v_new: jnp.ndarray, cur_index: jnp.ndarray,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Insert (b, 1, KH, hd) new K/V at cur_index along the S axis.
+    """Insert (b, 1, KH, hd) new K/V into layer ``layer`` of the stacked
+    (L, b, S, KH, hd) caches at row ``cur_index``, in place: one row per
+    slot is written and no layer slab is materialized.
 
     ``cur_index`` may be a scalar (lockstep batch) or a (b,) vector of
-    per-slot write positions (continuous batching).
+    per-slot write positions (continuous batching).  Out-of-range rows
+    clamp, as ``dynamic_update_slice`` does.
     """
     cur = jnp.asarray(cur_index)
     # kv_cast = astype for float caches, round-to-scale for int8 arenas
     if cur.ndim == 1:
-        row = jax.vmap(
-            lambda c, n, i: jax.lax.dynamic_update_slice_in_dim(c, n, i, axis=0)
-        )
-        return (row(k_cache, kv_cast(k_new, k_cache.dtype), cur),
-                row(v_cache, kv_cast(v_new, v_cache.dtype), cur))
-    k_cache = jax.lax.dynamic_update_slice_in_dim(
-        k_cache, kv_cast(k_new, k_cache.dtype), cur_index, axis=1
-    )
-    v_cache = jax.lax.dynamic_update_slice_in_dim(
-        v_cache, kv_cast(v_new, v_cache.dtype), cur_index, axis=1
-    )
-    return k_cache, v_cache
+        at = (layer, jnp.arange(k_new.shape[0]), cur)
+        return (k_stack.at[at].set(kv_cast(k_new[:, 0], k_stack.dtype),
+                                   mode="clip"),
+                v_stack.at[at].set(kv_cast(v_new[:, 0], v_stack.dtype),
+                                   mode="clip"))
+    start = (layer, 0, cur, 0, 0)
+    return (jax.lax.dynamic_update_slice(
+                k_stack, kv_cast(k_new, k_stack.dtype)[None], start),
+            jax.lax.dynamic_update_slice(
+                v_stack, kv_cast(v_new, v_stack.dtype)[None], start))
 
 
 # ---------------------------------------------------------------------------
@@ -398,42 +399,51 @@ def cache_update(
 # ---------------------------------------------------------------------------
 #
 # The paged pool (serving/cache.py) replaces per-slot max-length rows with
-# a (n_pages, page_size, KH, hd) arena; each slot owns a block-table row
-# of page ids.  Decode resolves the indirection inside the fused tick:
-# ``paged_cache_update`` scatters the new K/V at (page, offset) derived
-# from cur_index, ``gather_pages`` materializes the slot's dense view for
-# the unchanged ``decode_attention``.  Parity with the dense path is
-# exact: positions beyond cur_index gather recycled-page garbage, but the
-# ``pos <= cur`` mask sends them to NEG_INF and ``exp(NEG_INF - m)``
-# underflows to fp32 zero, so softmax sums (and the prob-weighted V
-# contraction, 0 * finite = 0) are bit-identical to the zero-padded
-# dense rows.  Page id 0 is the pool's trash page: freed slots keep
-# all-zero table rows and cur = 0, so their stale tick writes land there.
+# a (L, n_pages, page_size, KH, hd) arena per KV leaf; each slot owns a
+# block-table row of page ids.  Decode resolves the indirection inside
+# the fused tick, straight on the stacked arena at the layer's index:
+# ``paged_cache_update`` scatters the new K/V at (layer, page, offset)
+# derived from cur_index, ``gather_pages`` materializes the slot's dense
+# view for the unchanged ``decode_attention``.  Parity with the dense
+# path is exact: positions beyond cur_index gather recycled-page garbage,
+# but the ``pos <= cur`` mask sends them to NEG_INF and
+# ``exp(NEG_INF - m)`` underflows to fp32 zero, so softmax sums (and the
+# prob-weighted V contraction, 0 * finite = 0) are bit-identical to the
+# zero-padded dense rows.  Page id 0 is the pool's trash page: freed
+# slots keep all-zero table rows and cur = 0, so their stale tick writes
+# land there.
 
 
 @jax.named_scope("kv_write")
 def paged_cache_update(
-    k_arena: jnp.ndarray,  # (P, page_size, KH, hd)
+    k_arena: jnp.ndarray,  # (L, P, page_size, KH, hd)
     v_arena: jnp.ndarray,
+    layer,                 # int32 scalar layer index
     k_new: jnp.ndarray,    # (b, 1, KH, hd)
     v_new: jnp.ndarray,
     page_table: jnp.ndarray,  # (b, pages_per_slot) int32 page ids
     cur_index: jnp.ndarray,   # (b,) write positions
     page_size: int,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Scatter the new K/V of every slot through its block table."""
+    """Scatter the new K/V of every slot through its block table into
+    layer ``layer`` of the arena, in place."""
     cur = jnp.asarray(cur_index)
     pid = jnp.take_along_axis(
         page_table, (cur // page_size)[:, None], axis=1)[:, 0]  # (b,)
     off = cur % page_size
-    return (k_arena.at[pid, off].set(kv_cast(k_new[:, 0], k_arena.dtype)),
-            v_arena.at[pid, off].set(kv_cast(v_new[:, 0], v_arena.dtype)))
+    return (k_arena.at[layer, pid, off].set(kv_cast(k_new[:, 0],
+                                                    k_arena.dtype)),
+            v_arena.at[layer, pid, off].set(kv_cast(v_new[:, 0],
+                                                    v_arena.dtype)))
 
 
 @jax.named_scope("gather_pages")
-def gather_pages(arena: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
-    """(P, page_size, KH, hd) x (b, n) block table -> dense (b, n*ps, KH, hd)
-    per-slot view for ``decode_attention``."""
-    pages = jnp.take(arena, page_table, axis=0)  # (b, n, ps, KH, hd)
+def gather_pages(arena: jnp.ndarray, layer,
+                 page_table: jnp.ndarray) -> jnp.ndarray:
+    """(L, P, page_size, KH, hd) arena, layer index and (b, n) block
+    table -> dense (b, n*ps, KH, hd) per-slot view for
+    ``decode_attention``, gathered straight from the stack.  An
+    out-of-range page id reads the fill value, as ``jnp.take`` does."""
+    pages = arena.at[layer, page_table].get(mode="fill")  # (b, n, ps, KH, hd)
     b, n, ps = pages.shape[:3]
     return pages.reshape(b, n * ps, *pages.shape[3:])

@@ -12,7 +12,10 @@ All blocks run in one of four modes:
             prompt length (serving prefix-sharing resume)
   decode  — one token, consumes + emits state
 
-The state pytree leaves carry NO group axis here; the model stacks them.
+In prefill and chunk the state leaves carry NO layer axis; the model
+stacks them.  In decode the model passes the whole stacked state (leading
+layer axis) with the layer's index, and the block reads and writes its
+own layer of the stack in place.
 """
 
 from __future__ import annotations
@@ -82,6 +85,7 @@ def block_apply(
     mode: str,  # train | prefill | decode
     rope_cs: Optional[Tuple[jnp.ndarray, jnp.ndarray]],  # cos/sin (b,s,hd/2)
     state: Optional[Dict[str, jnp.ndarray]] = None,
+    layer=None,  # decode: this layer's index into the stacked state
     cur_index: Optional[jnp.ndarray] = None,
     page_table: Optional[jnp.ndarray] = None,  # (b, pages) paged decode
     page_size: int = 0,
@@ -114,39 +118,35 @@ def block_apply(
             k = constrain(apply_rope(k, cos, sin), "dp", s_ax, None, None)
         if mode == "decode":
             assert state is not None and cur_index is not None
+            # the constrain templates keep the stacks on the pool's
+            # placement (layer axis unsharded, slots or pages over dp,
+            # head_dim over 'model') so the sharded cache round-trips the
+            # tick without rematerialization: the scatter of the new rows
+            # drops the sharding under GSPMD otherwise
             if page_table is not None:
                 # block-table path: KV leaves are the shared page arena
-                # (n_pages, page_size, KH, hd); scatter through the table,
-                # then gather the slot's dense view for the same
+                # (L, n_pages, page_size, KH, hd); scatter through the
+                # table, then gather the slot's dense view for the same
                 # decode_attention (bit-exact vs the row path — see
-                # attention.py "paged decode").  The constrain templates
-                # match the row path because the arena's page axis sits
-                # where the slot axis was (pool_shardings rules).
+                # attention.py "paged decode").  The arena's page axis
+                # sits where the slot axis was (pool_shardings rules).
                 kc, vc = attn.paged_cache_update(
-                    state["k"], state["v"], k, v, page_table, cur_index,
-                    page_size)
-                kc = constrain(kc, "dp", None, None, "model")
-                vc = constrain(vc, "dp", None, None, "model")
-                kv = constrain(attn.gather_pages(kc, page_table),
+                    state["k"], state["v"], layer, k, v, page_table,
+                    cur_index, page_size)
+                kc = constrain(kc, None, "dp", None, None, "model")
+                vc = constrain(vc, None, "dp", None, None, "model")
+                kv = constrain(attn.gather_pages(kc, layer, page_table),
                                "dp", None, None, "model")
-                vv = constrain(attn.gather_pages(vc, page_table),
+                vv = constrain(attn.gather_pages(vc, layer, page_table),
                                "dp", None, None, "model")
-                o = attn.decode_attention(q, kv, vv, cur_index,
-                                          policy=policy)
-                new_state = {"k": kc, "v": vc}
             else:
                 kc, vc = attn.cache_update(
-                    state["k"], state["v"], k, v, cur_index)
-                # the vmap'd per-slot row write lowers to a scatter, and
-                # GSPMD drops the cache sharding across it — re-pin (slots
-                # over dp, head_dim over 'model', the decode-cache policy)
-                # so the sharded cache round-trips the tick without
-                # rematerialization
-                kc = constrain(kc, "dp", None, None, "model")
-                vc = constrain(vc, "dp", None, None, "model")
-                o = attn.decode_attention(q, kc, vc, cur_index,
-                                          policy=policy)
-                new_state = {"k": kc, "v": vc}
+                    state["k"], state["v"], layer, k, v, cur_index)
+                kc = constrain(kc, None, "dp", None, None, "model")
+                vc = constrain(vc, None, "dp", None, None, "model")
+                kv, vv = kc[layer], vc[layer]
+            o = attn.decode_attention(q, kv, vv, cur_index, policy=policy)
+            new_state = {"k": kc, "v": vc}
         elif mode == "chunk":
             # chunked prefill: the carry holds the KV of every earlier
             # chunk; append this chunk's and attend the new rows against
@@ -172,14 +172,18 @@ def block_apply(
         if mode == "decode":
             assert state is not None
             out, conv_s, ssm_s = mb.mamba_decode_step(
-                params["mamba"], h, state["conv"], state["ssm"],
+                params["mamba"], h, state["conv"][layer], state["ssm"][layer],
                 d_inner=cfg.d_inner, d_state=cfg.ssm_state, dt_rank=cfg.dt_rank_,
             )
-            # same re-pin as the KV path: keep the SSM/conv states on the
+            # written back at this layer's index of the stack; same
+            # re-pin as the KV path: keep the SSM/conv states on the
             # decode-cache placement (d_inner over 'model') tick to tick
-            conv_s = constrain(conv_s, "dp", None, "model")
-            ssm_s = constrain(ssm_s, "dp", "model", None)
-            new_state = {"conv": conv_s, "ssm": ssm_s}
+            conv_s = jax.lax.dynamic_update_index_in_dim(
+                state["conv"], conv_s, layer, 0)
+            ssm_s = jax.lax.dynamic_update_index_in_dim(
+                state["ssm"], ssm_s, layer, 0)
+            new_state = {"conv": constrain(conv_s, None, "dp", None, "model"),
+                         "ssm": constrain(ssm_s, None, "dp", "model", None)}
         elif mode == "prefill":
             out, (conv_s, ssm_s) = mb.mamba_apply(
                 params["mamba"], h, d_inner=cfg.d_inner, d_state=cfg.ssm_state,

@@ -112,28 +112,30 @@ def _dec_stack(cfg: ArchConfig, params: Params, x, enc_out, *, mode: str,
     has_state = mode in ("prefill", "decode", "chunk")
     consumes_state = mode in ("decode", "chunk")
 
-    def body(x, group):
-        lp, st = group
+    def apply(x, lp, st, layer):
         h = norm_apply(cfg.norm, lp["norm1"], x, eps=cfg.norm_eps, policy=policy)
         q, k, v = attn.qkv(lp["self_attn"], h)
         new_st = {} if has_state else None
         if mode == "decode":
+            # ``st`` is the whole stacked state; this layer reads and
+            # writes its own index of it in place (transformer._stack)
             if page_table is not None:
                 # paged self-attention KV (shared arena, see attention.py);
                 # cross-KV stays slot-indexed — it is request-specific
                 # (computed from this request's frames) and full-length
                 # from prefill, so paging buys nothing there.
                 kc, vc = attn.paged_cache_update(
-                    st["k"], st["v"], k, v, page_table, cur_index, page_size)
-                o = attn.decode_attention(
-                    q, attn.gather_pages(kc, page_table),
-                    attn.gather_pages(vc, page_table), cur_index,
-                    policy=policy)
+                    st["k"], st["v"], layer, k, v, page_table, cur_index,
+                    page_size)
+                kv = attn.gather_pages(kc, layer, page_table)
+                vv = attn.gather_pages(vc, layer, page_table)
             else:
-                kc, vc = attn.cache_update(st["k"], st["v"], k, v, cur_index)
-                o = attn.decode_attention(q, kc, vc, cur_index, policy=policy)
+                kc, vc = attn.cache_update(st["k"], st["v"], layer, k, v,
+                                           cur_index)
+                kv, vv = kc[layer], vc[layer]
+            o = attn.decode_attention(q, kv, vv, cur_index, policy=policy)
             new_st = {"k": kc, "v": vc, "ck": st["ck"], "cv": st["cv"]}
-            ck, cv = st["ck"], st["cv"]
+            ck, cv = st["ck"][layer], st["cv"][layer]
         elif mode == "chunk":
             # chunked prefill: append this chunk's self-KV to the carry
             # and attend the new rows against the whole prefix; cross-KV
@@ -171,6 +173,18 @@ def _dec_stack(cfg: ArchConfig, params: Params, x, enc_out, *, mode: str,
         h = norm_apply(cfg.norm, lp["norm3"], x, eps=cfg.norm_eps, policy=policy)
         x = x + mlp_mod.mlp_apply(lp["mlp"], h, act=cfg.act)
         return x, new_st
+
+    if mode == "decode":
+        def body(carry, group):
+            return apply(carry[0], group[0], carry[1], group[1]), None
+
+        layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+        (x, new_states), _ = jax.lax.scan(
+            body, (x, states), (params["dec_layers"], layers))
+        return x, new_states
+
+    def body(x, group):
+        return apply(x, group[0], group[1], None)
 
     xs = (params["dec_layers"], states if consumes_state else None)
     fn = jax.checkpoint(body) if (cfg.remat and mode == "train") else body

@@ -7,7 +7,8 @@ per-layer pipeline), with ``jax.checkpoint`` around the scanned body for
 remat.  Heterogeneous stacks (Jamba) unroll the period *inside* the body.
 
 States (decode caches) are stacked per superblock position with a leading
-(n_groups, ...) axis and threaded through the same scan.
+(n_groups, ...) axis.  Prefill emits them from the scan; decode carries
+the stacks through it and updates each layer's index in place.
 """
 
 from __future__ import annotations
@@ -110,44 +111,55 @@ def unembed(cfg: ArchConfig, params: Params, x: jnp.ndarray) -> jnp.ndarray:
 def _stack(cfg: ArchConfig, params: Params, x: jnp.ndarray, *, mode: str,
            rope_cs, states=None, cur_index=None, page_table=None,
            page_size: int = 0):
-    """Scan the layer stack.  Returns (x, new_states or None)."""
+    """Scan the layer stack.  Returns (x, new_states or None).
+
+    The stacked weights ride the scan as ``xs``.  Prefill and chunk emit
+    each layer's states as ``ys``.  Decode carries the stacked states
+    instead: each layer reads and writes its own index of the one stack
+    in place, so no layer's slab is sliced out, written back, or copied
+    into the donated output.
+    """
     kinds = cfg.block_kinds()
     has_state = mode in ("prefill", "decode", "chunk")
-    consumes_state = mode in ("decode", "chunk")
 
-    def body(x, group):
-        gparams, gstates = group
-        new_gstates = {} if has_state else None
+    def apply(x, gparams, gstates, layer):
+        new_gstates = {}
         for i, kind in enumerate(kinds):
-            st = gstates[f"pos{i}"] if (gstates is not None
-                                        and consumes_state) else None
-            x, ns = blocks.block_apply(
+            x, new_gstates[f"pos{i}"] = blocks.block_apply(
                 cfg, kind, gparams[f"pos{i}"], x, mode=mode, rope_cs=rope_cs,
-                state=st, cur_index=cur_index, page_table=page_table,
+                state=None if gstates is None else gstates[f"pos{i}"],
+                layer=layer, cur_index=cur_index, page_table=page_table,
                 page_size=page_size,
             )
-            if has_state:
-                new_gstates[f"pos{i}"] = ns
-        return x, new_gstates
+        return x, (new_gstates if has_state else None)
 
-    xs = (params["layers"], states if consumes_state else None)
+    if mode == "decode":
+        def body(carry, group):
+            return apply(carry[0], group[0], carry[1], group[1]), None
+
+        init = (x, states)
+        xs = (params["layers"], jnp.arange(cfg.n_groups, dtype=jnp.int32))
+    else:
+        def body(x, group):
+            return apply(x, group[0], group[1], None)
+
+        init = x
+        xs = (params["layers"], states if mode == "chunk" else None)
+
     if cfg.scan_layers:
         fn = jax.checkpoint(body) if (cfg.remat and mode == "train") else body
         # the scope names what the scan itself adds: each layer's slice
-        # of the stacked weights and states, and the write of its new
-        # states back into the stack
+        # of the stacked weights (outside decode also of the states, and
+        # the write of the new states into a fresh stack)
         with jax.named_scope("layer_scan"):
-            x, new_states = jax.lax.scan(fn, x, xs)
+            out, ys = jax.lax.scan(fn, init, xs)
     else:
-        outs = []
+        out, outs = init, []
         for gi in range(cfg.n_groups):
-            grp = jax.tree.map(lambda a: a[gi], xs)
-            x, ns = body(x, grp)
-            outs.append(ns)
-        new_states = (
-            jax.tree.map(lambda *ls: jnp.stack(ls), *outs) if has_state else None
-        )
-    return x, (new_states if has_state else None)
+            out, y = body(out, jax.tree.map(lambda a: a[gi], xs))
+            outs.append(y)
+        ys = jax.tree.map(lambda *ls: jnp.stack(ls), *outs)
+    return out if mode == "decode" else (out, ys)
 
 
 # ---------------------------------------------------------------------------

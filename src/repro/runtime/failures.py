@@ -56,8 +56,8 @@ class ServeFaultInjector:
     each tick boundary (tick N = the N'th fused decode tick of the run,
     0-based).  One injector scripts one run — build a fresh one per
     ``Engine.run`` (events are consumed; ``reset()`` re-arms).  Engines
-    with an injector should skip ``warmup`` (it runs the same loop and
-    would consume the script).
+    with an injector that call ``warmup`` re-arm it with ``reset()``
+    afterwards (the warm-up runs the same loop and consumes the script).
 
     * ``fail_ticks`` — multiset of tick numbers; each occurrence raises
       one :class:`TickFailure` before that tick executes (so

@@ -1,12 +1,16 @@
 """Per-arch smoke tests + prefill/decode vs full-forward consistency."""
 
+import dataclasses
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import configs
-from repro.models import api
+from repro.models import api, blocks, transformer
+from repro.serving.cache import make_paged_cache, remap_kv_leaves
 
 ARCHS = list(configs.ARCH_IDS)
 
@@ -106,6 +110,124 @@ class TestDecodeConsistency:
                 np.asarray(lg[:, 0], np.float32),
                 np.asarray(full[:, t], np.float32),
                 atol=tol, rtol=tol)
+
+
+def _slab_decode_step(cfg, params, states, cur_index, token,
+                      page_table=None, page_size=0):
+    """Decode with each layer's state slab sliced out of the stack,
+    updated, and stacked anew: the placement the carried stack replaced.
+    Same block code, so any difference is the placement's."""
+    rope_cs = transformer._rope_info(cfg, token.shape[0], 1, None,
+                                     cur_index=cur_index)
+    x = transformer.embed_tokens(cfg, params, token)
+    outs = []
+    for gi in range(cfg.n_groups):
+        gparams = jax.tree.map(lambda a: a[gi], params["layers"])
+        slab = jax.tree.map(lambda a: a[gi:gi + 1], states)
+        new = {}
+        for i, kind in enumerate(cfg.block_kinds()):
+            x, ns = blocks.block_apply(
+                cfg, kind, gparams[f"pos{i}"], x, mode="decode",
+                rope_cs=rope_cs, state=slab[f"pos{i}"], layer=0,
+                cur_index=cur_index, page_table=page_table,
+                page_size=page_size)
+            new[f"pos{i}"] = jax.tree.map(lambda a: a[0], ns)
+        outs.append(new)
+    return (transformer.unembed(cfg, params, x),
+            jax.tree.map(lambda *ls: jnp.stack(ls), *outs))
+
+
+class TestInPlaceDecodeStack:
+    """Decode carries the stacked states through the layer scan, and each
+    layer reads and writes its own index of them in place.  Against the
+    per-layer slab placement: the same logits, tokens and states bit for
+    bit over several ticks, the KV stacks changed only at the rows the
+    ticks wrote, and the unrolled stack (``scan_layers=False``) equal to
+    the scanned one."""
+
+    @pytest.mark.parametrize("arch,over,pool,kv_dtype", [
+        ("tinyllama-1.1b", {}, "paged", None),         # dense GQA arena
+        ("tinyllama-1.1b", {}, "paged", jnp.int8),     # int8 arena
+        ("tinyllama-1.1b", {}, "slot", None),          # dense slot rows
+        ("jamba-1.5-large-398b", {"capacity_factor": 8.0}, "paged",
+         None),                                        # hybrid SSM + MoE
+        ("granite-moe-1b-a400m", {"capacity_factor": 8.0}, "slot", None),
+    ], ids=["dense-paged", "int8-paged", "dense-slot", "hybrid-moe-paged",
+            "moe-slot"])
+    def test_decode_ticks_match_slab_placement(self, arch, over, pool,
+                                               kv_dtype):
+        cfg = configs.get_smoke(arch, dtype="float32",
+                                param_dtype="float32", **over)
+        params = api.init(cfg, jax.random.key(4))
+        rng = np.random.RandomState(4)
+        b, s_max, ps, n_ticks = 3, 16, 4, 3
+        if pool == "paged":
+            n_pages = b * (s_max // ps) + 1  # page 0 is the trash page
+            cache = make_paged_cache(cfg, b, n_pages, ps, jnp.float32,
+                                     kv_dtype=kv_dtype)
+            table = rng.permutation(np.arange(1, n_pages)).reshape(b, -1)
+            kw = {"page_table": jnp.asarray(table, jnp.int32)}
+        else:
+            cache = remap_kv_leaves(api.make_cache(cfg, b, s_max,
+                                                   jnp.float32), kv_dtype)
+            ps, kw = 0, {}
+
+        def fill(a):  # distinct stale contents everywhere
+            if jnp.issubdtype(a.dtype, jnp.integer):
+                return jnp.asarray(rng.randint(-127, 128, a.shape), a.dtype)
+            return jnp.asarray(rng.randn(*a.shape) * 0.5, a.dtype)
+
+        cache = jax.tree.map(fill, cache)
+        cur0 = np.array([2, 7, 13], np.int32)
+        tok0 = jnp.asarray(rng.randint(0, cfg.vocab, (b, 1)), jnp.int32)
+        steps = {
+            "slab": _slab_decode_step,
+            "scan": transformer.decode_step,
+            "unrolled": transformer.decode_step,
+        }
+        runs = {}
+        for name, step in steps.items():
+            c = (dataclasses.replace(cfg, scan_layers=False)
+                 if name == "unrolled" else cfg)
+            fn = jax.jit(functools.partial(step, c, page_size=ps))
+            states, tok, logits = cache, tok0, []
+            for t in range(n_ticks):
+                lg, states = fn(params, states, jnp.asarray(cur0 + t), tok,
+                                **kw)
+                logits.append(np.asarray(lg))
+                tok = jnp.argmax(lg[:, -1], axis=-1)[:, None].astype(
+                    jnp.int32)
+            runs[name] = (logits, states)
+
+        ref_logits, ref_states = runs["slab"]
+        for name in ("scan", "unrolled"):
+            logits, states = runs[name]
+            for t in range(n_ticks):
+                np.testing.assert_array_equal(logits[t], ref_logits[t],
+                                              err_msg=f"{name} tick {t}")
+            for a, r in zip(jax.tree.leaves(states),
+                            jax.tree.leaves(ref_states)):
+                assert a.dtype == r.dtype and a.shape == r.shape
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(r),
+                                              err_msg=name)
+
+        # the in-place writes touch only (layer, page or slot, row) of the
+        # positions the ticks wrote; every other KV row is bit-identical
+        leaves = jax.tree_util.tree_flatten_with_path(cache)[0]
+        for (path, old), new in zip(leaves,
+                                    jax.tree.leaves(runs["scan"][1])):
+            if path[-1].key not in ("k", "v"):
+                continue
+            old, new = np.asarray(old), np.asarray(new)
+            wrote = np.zeros(old.shape[:3], bool)
+            for t in range(n_ticks):
+                for i, c in enumerate(cur0 + t):
+                    if pool == "paged":
+                        wrote[:, table[i, c // ps], c % ps] = True
+                    else:
+                        wrote[:, i, c] = True
+            np.testing.assert_array_equal(new[~wrote], old[~wrote])
+            assert (new[wrote] != old[wrote]).any()
 
 
 class TestParamAccounting:

@@ -380,20 +380,20 @@ class TestVectorCurIndex:
         from repro.layers import attention as attn
 
         r = np.random.RandomState(10)
-        b, S, kh, hd = 3, 12, 2, 4
-        kc = jnp.asarray(r.randn(b, S, kh, hd).astype(np.float32))
-        vc = jnp.asarray(r.randn(b, S, kh, hd).astype(np.float32))
+        L, b, S, kh, hd = 2, 3, 12, 2, 4
+        kc = jnp.asarray(r.randn(L, b, S, kh, hd).astype(np.float32))
+        vc = jnp.asarray(r.randn(L, b, S, kh, hd).astype(np.float32))
         kn = jnp.asarray(r.randn(b, 1, kh, hd).astype(np.float32))
         vn = jnp.asarray(r.randn(b, 1, kh, hd).astype(np.float32))
         cur = jnp.asarray([0, 5, 11], jnp.int32)
-        k2, v2 = attn.cache_update(kc, vc, kn, vn, cur)
+        k2, v2 = attn.cache_update(kc, vc, 1, kn, vn, cur)
         for i in range(b):
-            k1, v1 = attn.cache_update(kc[i:i + 1], vc[i:i + 1],
+            k1, v1 = attn.cache_update(kc[:, i:i + 1], vc[:, i:i + 1], 1,
                                        kn[i:i + 1], vn[i:i + 1],
                                        jnp.int32(cur[i]))
-            np.testing.assert_array_equal(np.asarray(k2[i:i + 1]),
+            np.testing.assert_array_equal(np.asarray(k2[:, i:i + 1]),
                                           np.asarray(k1))
-            np.testing.assert_array_equal(np.asarray(v2[i:i + 1]),
+            np.testing.assert_array_equal(np.asarray(v2[:, i:i + 1]),
                                           np.asarray(v1))
 
 

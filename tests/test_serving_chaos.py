@@ -12,8 +12,9 @@ the full containment contract:
   fault-free run (greedy fp32),
 * the failure counters on :class:`ServeMetrics` account for the event.
 
-Engines with an injector never call ``warmup`` — it runs the same loop
-and would consume the script.
+Engines with an injector call ``warmup`` only with a ``reset()`` of the
+injector after it — the warm-up runs the same loop and would consume the
+script.
 """
 
 import json
@@ -143,6 +144,10 @@ class TestDeadlines:
                          deadline_ms=5000.0)
         inj = ServeFaultInjector(skew={3: 100.0})
         eng = Engine(cfg, params, EngineConfig(n_slots=2, injector=inj))
+        # compile first: the 5 s deadline is for serving, and compiling
+        # on a loaded machine can outlast it before tick 3's skew lands
+        eng.warmup([6, 9])
+        inj.reset()
         outs, m = eng.run(reqs)
         for rid in (0, 1):
             assert outs[rid].finish_reason == FINISH_DEADLINE

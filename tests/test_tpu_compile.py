@@ -6,19 +6,27 @@ each kernel here is lowered with ``interpret=False`` at internlm2-1.8b
 widths (d_model 2048, 16 q heads / 8 kv heads, head_dim 128, vocab 92544)
 and compiled for one chip of a ``v5e:2x2`` topology.  A compile that
 passes shows the kernel is a ``tpu_custom_call``; it says nothing about
-results or times, which only a chip run gives.
+results or times, which only a chip run gives.  The engine's paged decode
+tick is compiled the same way, at 2 layers, to check where it keeps the
+KV arena.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
 and every test worker imports this file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import configs
 from repro.kernels import ops
+from repro.models import api
+from repro.serving import Engine, EngineConfig
+from repro.serving.cache import make_paged_cache
 
 D_MODEL = 2048
 N_HEADS, N_KV_HEADS, HEAD_DIM = 16, 8, 128
@@ -119,3 +127,35 @@ def test_gs_fixed_softmax(one_chip):
 def test_gs_fixed_rmsnorm(one_chip):
     _compile(lambda x, g: ops.gs_fixed_rmsnorm(x, 0.03, g, interpret=False),
              one_chip, ((256, D_MODEL), jnp.int8), ((D_MODEL,), jnp.float32))
+
+
+def test_paged_tick_updates_arena_in_place(one_chip):
+    """The decode tick at internlm2-1.8b widths (2 layers, a 65-page
+    arena) updates the donated KV arena in place: the optimized HLO holds
+    no copy of a whole KV stack, and the whole cache is aliased to the
+    tick's cache output."""
+    cfg = configs.get_config("internlm2-1.8b", n_layers=2)
+    n, ps = 4, 16
+    sds = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+        a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(sds, api.param_specs(cfg))
+    eng = Engine(cfg, params, EngineConfig(
+        n_slots=n, s_max=256, pool="paged", page_size=ps, n_pages=65))
+    cache = jax.tree.map(sds, jax.eval_shape(lambda: make_paged_cache(
+        cfg, n, eng._n_pages, ps, jnp.dtype(cfg.dtype))))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip)
+    compiled = eng._tick_fn(False, 0, True).lower(
+        params, cache, i32(n, eng._pages_per_slot), i32(n), i32(n, 1),
+        jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip),
+        i32(n), i32(n),
+        jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                             sharding=one_chip)).compile()
+    leaves = jax.tree.leaves(cache)
+    stack = "[" + ",".join(map(str, leaves[0].shape)) + "]"
+    copies = [line for line in compiled.as_text().splitlines()
+              if re.search(r" copy(-start)?\(", line)
+              and stack in re.split(r" copy(-start)?\(", line)[0]]
+    assert not copies, copies[:2]
+    assert compiled.memory_analysis().alias_size_in_bytes == sum(
+        a.size * a.dtype.itemsize for a in leaves)
