@@ -2,17 +2,20 @@
 
 Once the window has closed, a sample of the finished requests is run
 through the float32 reference (``bench/reference/dense_gqa.py``) over
-its prompt and every token the program served.  The sample is spread
-over the slots: it holds the longest request, every request that was
-being served beside it at the busiest moment of its life (requests live
-at one moment sit in different slots), and then others drawn from the
-seed, up to the mix's ``check.requests``.  For each served (greedy)
-token the gap is the reference's best logit at that position minus the
-reference's logit of the served token: 0 where the two agree, and small
-where a near tie fell the other way under the program's rounding.  The
-widest gap of the sample is compared with the cell's limit
-(``bench/limits/<cell>.json``), which was set between the program's
-readings over a dozen seeds and those of its int8 path (the control).
+its prompt and every token the program served, with the arithmetic the
+configuration file states (its ``rms_norm_eps``, ``rope_theta`` and any
+of Granite's multipliers, scaling and tied head), not the program's.
+The sample is spread over the slots: it holds the longest request,
+every request that was being served beside it at the busiest moment of
+its life (requests live at one moment sit in different slots), and then
+others drawn from the seed, up to the mix's ``check.requests``.  For
+each served (greedy) token the gap is the reference's best logit at that
+position minus the reference's logit of the served token: 0 where the
+two agree, and small where a near tie fell the other way under the
+program's rounding.  The widest gap of the sample is compared with the
+cell's limit (``bench/limits/<cell>.json``), which was set between the
+program's readings over a dozen seeds and those of its int8 path (the
+control).
 
 A request the engine failed with ``numeric_error`` also makes the run
 incorrect: it served something that is not a token.
@@ -74,9 +77,12 @@ def sample(outs, arrivals: Dict[int, float], seed: int,
     return picked
 
 
-def reference_gaps(params, cfg, mix: dict, prompts: Dict[int, np.ndarray],
-                   outs, rids: List[int]) -> Dict[int, np.ndarray]:
-    """Gap of every served token of ``rids``, per request."""
+def reference_gaps(params, config: dict, mix: dict,
+                   prompts: Dict[int, np.ndarray], outs,
+                   rids: List[int]) -> Dict[int, np.ndarray]:
+    """Gap of every served token of ``rids``, per request, by the
+    reference with the arithmetic of the configuration file ``config``."""
+    arch = dense_gqa.arithmetic(config)
     n_out = int(mix["output"]["max"])
     s_pad = dense_gqa.padded_len(max(mix["prompt"]["grid"]) + n_out)
     gaps = {}
@@ -85,17 +91,16 @@ def reference_gaps(params, cfg, mix: dict, prompts: Dict[int, np.ndarray],
         seq = np.concatenate([prompts[rid], served[:-1]])
         seq = np.pad(seq, (0, s_pad - len(seq)))
         gaps[rid] = dense_gqa.served_gaps(
-            params, seq, len(prompts[rid]), served, n_out=n_out,
-            eps=cfg.norm_eps, theta=cfg.rope_theta)
+            params, seq, len(prompts[rid]), served, n_out=n_out, arch=arch)
     return gaps
 
 
-def compare(params, cfg, cell, requests, outs, seed: int) -> dict:
+def compare(params, cell, requests, outs, seed: int) -> dict:
     """The verdict and each compared number beside its limit."""
     prompts = {r.rid: r.prompt for r in requests}
     arrivals = {r.rid: float(r.arrival_time) for r in requests}
     rids = sample(outs, arrivals, seed, int(cell.mix["check"]["requests"]))
-    gaps = reference_gaps(params, cfg, cell.mix, prompts, outs, rids)
+    gaps = reference_gaps(params, cell.config, cell.mix, prompts, outs, rids)
     # no finished request leaves nothing to compare: not correct
     widest = max((float(g.max()) for g in gaps.values()), default=None)
     n_numeric = sum(1 for o in outs.values()

@@ -44,7 +44,7 @@ def reading(cell, seed: int, seconds: float, cfg_extra=None, *,
     st.engine = None
     gc.collect()
     t0 = time.perf_counter()
-    v = check.compare(st.params, st.cfg, cell, win.requests, win.outs, seed)
+    v = check.compare(st.params, cell, win.requests, win.outs, seed)
     check_s = time.perf_counter() - t0
     gaps = v["gaps"]
     return {"seed": seed, "kind": kind,

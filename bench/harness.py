@@ -148,10 +148,13 @@ class Record:
     trace: Optional[dict] = None  # bench/trace.py summary
 
 
-def arch_sizes(cfg) -> dict:
+def arch_sizes(cfg, config: dict) -> dict:
+    """The sizes the benchmark makes weights of: the program's, with the
+    head tied where the configuration file ``config`` says so."""
     return {"n_layers": cfg.n_layers, "d_model": cfg.d_model,
             "n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
-            "head_dim": cfg.head_dim_, "d_ff": cfg.d_ff, "vocab": cfg.vocab}
+            "head_dim": cfg.head_dim_, "d_ff": cfg.d_ff, "vocab": cfg.vocab,
+            "tie_word_embeddings": bool(config.get("tie_word_embeddings"))}
 
 
 def build_cfg(cell: Cell, extra: Optional[dict] = None):
@@ -233,7 +236,7 @@ def set_up(cell: Cell, seed: int, warm_lengths, *, trace: bool = False,
 
     cfg = build_cfg(cell, cfg_extra)
     _check_sizes(cell, cfg)
-    arch = arch_sizes(cfg)
+    arch = arch_sizes(cfg, cell.config)
     mesh = sh = None
     if cell.chips > 1:
         from repro.launch.mesh import make_serving_mesh
@@ -356,8 +359,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
             metrics[m["name"]] = {"value": v, "unit": m["unit"]}
 
     t_check = time.perf_counter()
-    verdict = check.compare(st.params, st.cfg, cell, win.requests, win.outs,
-                            seed)
+    verdict = check.compare(st.params, cell, win.requests, win.outs, seed)
     log(f"reference check: {time.perf_counter() - t_check:.1f} s")
     dev = st.devs[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
@@ -388,14 +390,26 @@ _SIZE_KEYS = {"hidden_size": "d_model", "intermediate_size": "d_ff",
               "num_key_value_heads": "n_kv_heads", "vocab_size": "vocab",
               "rms_norm_eps": "norm_eps", "rope_theta": "rope_theta",
               "max_position_embeddings": "max_seq",
-              "tie_word_embeddings": "tie_embeddings"}
+              "tie_word_embeddings": "tie_embeddings",
+              "embedding_multiplier": "embedding_multiplier",
+              "attention_multiplier": "attention_multiplier",
+              "residual_multiplier": "residual_multiplier",
+              "logits_scaling": "logits_scaling"}
 
 
 def _check_sizes(cell: Cell, cfg) -> None:
     """The configuration file states what runs: its published keys must
-    match the program's configuration."""
+    match the program's configuration, and a key whose arithmetic the
+    program has no field for (so the reference would compute what the
+    program leaves out) stops set-up."""
     for key, field in _SIZE_KEYS.items():
-        if key in cell.config and cell.config[key] != getattr(cfg, field):
+        if key not in cell.config:
+            continue
+        if not hasattr(cfg, field):
+            raise BenchError(f"{cell.config_name}: the file states {key}="
+                             f"{cell.config[key]!r} but the program's "
+                             f"configuration has no field {field!r}")
+        if cell.config[key] != getattr(cfg, field):
             raise BenchError(f"{cell.config_name}: {key}="
                              f"{cell.config[key]!r} but the program runs "
                              f"{field}={getattr(cfg, field)!r}")

@@ -4,7 +4,8 @@ The benchmark makes the weights itself, so the reference never reads
 anything the program made.  The pytree follows the layout the program's
 engine takes (``repro.models`` dense stack, scanned over layers):
 
-    embed (V, d); lm_head (d, V); final_norm.scale (d,)
+    embed (V, d); lm_head (d, V), absent where the head is tied to
+    embed (``tie_word_embeddings``); final_norm.scale (d,)
     layers.pos0: norm1.scale, norm2.scale (L, d)
                  attn.wq (L, d, H, hd), attn.wk / attn.wv (L, d, KH, hd),
                  attn.wo (L, H, hd, d)
@@ -23,13 +24,15 @@ import jax.numpy as jnp
 
 
 def shapes(arch: dict) -> dict:
-    """Leaf shapes of the pytree, from the configuration's sizes."""
+    """Leaf shapes of the pytree, from the configuration's sizes and its
+    ``tie_word_embeddings``."""
     L, d, V = arch["n_layers"], arch["d_model"], arch["vocab"]
     H, KH, F = arch["n_heads"], arch["n_kv_heads"], arch["d_ff"]
     hd = arch.get("head_dim") or d // H
+    head = {} if arch.get("tie_word_embeddings") else {"lm_head": (d, V)}
     return {
         "embed": (V, d),
-        "lm_head": (d, V),
+        **head,
         "final_norm": {"scale": (d,)},
         "layers": {"pos0": {
             "norm1": {"scale": (L, d)},
