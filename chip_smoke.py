@@ -14,11 +14,13 @@ unless every request finishes by length, no kernel fell back to jnp, the
 prefill step lowers to compiled Mosaic kernels (``tpu_custom_call``), and
 the Pallas prefill logits agree with the exact jnp model.
 
-Four chips (``--chips 4``, and only that phase).  granite-3-8b cut to 4
-layers runs in float32 sharded over a 1x4 serving mesh and unsharded on
-device 0; prefill and 16 decode steps' logits must agree.  Then the full
-40-layer granite-3-8b, whose float32 weights do not fit one chip, answers
-4 requests on the mesh.
+Four chips (``--chips 4``, and only that phase).  The published
+granite-3-8b (tied head, Granite's four scalars) cut to 4 layers runs in
+float32 through the Pallas kernels, sharded over a 1x4 serving mesh (the
+kernels under shard_map) and unsharded on device 0; prefill and 16
+decode steps' logits must agree.  Then the full 40-layer granite-3-8b,
+whose float32 weights do not fit one chip, answers 4 requests on the
+mesh through the Pallas kernels.
 
 The lines before the last report one smoke run; they are not benchmark
 numbers.  The last line, printed only when every check passed, is one
@@ -284,7 +286,8 @@ def four_chips(seed: int, check: Checks):
     rng = np.random.RandomState(seed)
 
     # sharded vs unsharded, same process, float32 at the highest precision
-    cut = configs.get_config("granite-3-8b", n_layers=4, dtype="float32")
+    cut = configs.get_config("granite-3-8b", n_layers=4, dtype="float32",
+                             kernel_impl="pallas")
     prompt = rng.randint(0, cut.vocab, (128,))
     n_dec = 16
     with jax.default_matmul_precision("highest"):
@@ -305,7 +308,7 @@ def four_chips(seed: int, check: Checks):
     check(max(errs) <= SHARDED_VS_SINGLE_REL_L2,
           "sharded logits agree with unsharded")
 
-    cfg = configs.get_config("granite-3-8b")
+    cfg = configs.get_config("granite-3-8b", kernel_impl="pallas")
     plen, gen = 256, 32
     engine = build_engine(cfg, EngineConfig(
         n_slots=4, s_max=plen + gen, seed=seed, pool="paged",
@@ -313,7 +316,8 @@ def four_chips(seed: int, check: Checks):
     split = sum(not x.sharding.is_fully_replicated
                 for x in jax.tree.leaves(engine.params))
     in_use = [d.memory_stats()["bytes_in_use"] for d in devs]
-    print(f"40-layer granite params: {api.param_count(cfg)} parameters, "
+    print(f"40-layer granite params (tied head): {api.param_count(cfg)} "
+          f"parameters, "
           f"{split}/{len(jax.tree.leaves(engine.params))} leaves partitioned"
           f" (the rest replicated); "
           f"bytes_in_use per device {in_use}")
@@ -325,7 +329,9 @@ def four_chips(seed: int, check: Checks):
     peak = [d.memory_stats()["peak_bytes_in_use"] for d in devs]
     print(f"serve: {len(outs)} requests, TTFT p50 {m.ttft_summary['p50']!r}"
           f" s, decode {m.decode_tok_per_s!r} tok/s; peak_bytes_in_use per "
-          f"device {peak}")
+          f"device {peak}; tick collectives {m.tick_collectives} "
+          f"({m.tick_collective_bytes} bytes a chip)")
+    check(m.kernel_fallbacks == 0, "no kernel fell back to jnp")
     check(len(outs) == len(reqs)
           and all(o.finish_reason == FINISH_LENGTH
                   and len(o.tokens) == gen for o in outs.values()),

@@ -59,7 +59,12 @@ class ArchConfig:
 
     # misc
     tie_embeddings: bool = False
-    scale_depth: float = 0.0  # minicpm depth-scaled residual (0 = off)
+    # Granite's published scalars (GraniteForCausalLM's config keys).  A
+    # scalar at its default adds no operation to the program.
+    embedding_multiplier: float = 1.0  # x = embed[t] * m
+    attention_multiplier: Optional[float] = None  # scores q.k * m; None -> 1/sqrt(hd)
+    residual_multiplier: float = 1.0  # x += m * block(x) (MiniCPM: scale_depth/sqrt(L))
+    logits_scaling: float = 1.0  # logits = head(x) / s
     act: str = "silu"  # silu | gelu
     dtype: str = "bfloat16"  # activation dtype
     param_dtype: str = "float32"

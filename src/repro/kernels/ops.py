@@ -21,6 +21,15 @@ the same BlockSpecs compile through Mosaic.  An explicit
 ``interpret=False`` compiles for a described chip from a CPU host
 (``tests/test_tpu_compile.py``).
 
+On a mesh (an activation context of :mod:`repro.runtime.sharding` is
+active, as inside the sharded serving and training steps) the ops the
+model calls run under ``jax.shard_map``: GSPMD has no partitioning rule
+for a Pallas call, so each chip runs the kernel on its own shard.  The
+rmsnorms (float and int8) shard their leading (batch) dimension over the
+context's data axes and keep the rest whole (the residual stream is
+replicated over 'model'); flash attention shards heads over 'model'.
+With no context the call is the plain kernel call.
+
 Every front-end routes through :func:`dispatch.call_with_fallback`.  By
 default a kernel that fails to trace/lower/compile raises.  With the
 fallback opted in (``REPRO_KERNEL_FALLBACK=1`` or
@@ -44,6 +53,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import common
 from repro.kernels import ref as _ref
@@ -58,6 +68,7 @@ from repro.kernels.gs_rsqrt import gs_rsqrt as _gs_rsqrt
 from repro.kernels.gs_rsqrt import gs_sqrt as _gs_sqrt
 from repro.kernels.gs_softmax import gs_softmax as _gs_softmax
 from repro.kernels.tuning import dispatch
+from repro.runtime import sharding as shr
 
 __all__ = [
     "flash_attention",
@@ -71,6 +82,30 @@ __all__ = [
     "gs_softmax",
     "gs_sqrt",
 ]
+
+
+def _on_mesh(body, args, specs):
+    """``body(*args)``; inside an activation context, under
+    ``jax.shard_map`` over its mesh with ``specs(mesh, dp)`` =
+    ``(in_specs, out_specs)``."""
+    ctx = shr.active_context()
+    if ctx is None:
+        return body(*args)
+    mesh, dp = ctx
+    in_specs, out_specs = specs(mesh, dp)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
+
+
+def _rows(body, x, *rest):
+    """A row-wise op ``body(x, *rest)``: ``x`` and the output shard their
+    leading dimension over the data axes, ``rest`` (the gain, the int8
+    scale) replicate."""
+    def specs(mesh, dp):
+        spec = shr.filter_pspec(P(dp or None), mesh, x.shape)
+        return (spec,) + (P(),) * len(rest), spec
+
+    return _on_mesh(body, (x,) + rest, specs)
 
 
 def _gs_kw(cfg):
@@ -112,10 +147,14 @@ def gs_softmax(x, *, p: int | None = None, **config):
 
 def gs_rmsnorm(x, gain, *, eps: float = 1e-6, p: int | None = None,
                **config):
-    cfg = dispatch.resolve("gs_rmsnorm", x.shape, x.dtype, {"p": p, **config})
-    return dispatch.call_with_fallback(
-        "gs_rmsnorm", lambda: _gs_rmsnorm(x, gain, eps=eps, **cfg),
-        lambda: _ref.rmsnorm(x, gain, eps=eps, **_gs_kw(cfg)))
+    def body(x, gain):
+        cfg = dispatch.resolve("gs_rmsnorm", x.shape, x.dtype,
+                               {"p": p, **config})
+        return dispatch.call_with_fallback(
+            "gs_rmsnorm", lambda: _gs_rmsnorm(x, gain, eps=eps, **cfg),
+            lambda: _ref.rmsnorm(x, gain, eps=eps, **_gs_kw(cfg)))
+
+    return _rows(body, x, gain)
 
 
 # -- fixed-point (int8) epilogues -------------------------------------------
@@ -151,12 +190,15 @@ def _fixed_rmsnorm_ref(x, scale, gain, eps):
 
 def gs_fixed_rmsnorm(x, scale, gain, *, eps: float = 1e-6,
                      p: int | None = None, **config):
-    cfg = dispatch.resolve("gs_fixed_rmsnorm", x.shape, x.dtype,
-                           {"p": p, **config})
-    return dispatch.call_with_fallback(
-        "gs_fixed_rmsnorm", lambda: _gs_fixed_rmsnorm(x, scale, gain,
-                                                      eps=eps, **cfg),
-        lambda: _fixed_rmsnorm_ref(x, scale, gain, eps))
+    def body(x, scale, gain):
+        cfg = dispatch.resolve("gs_fixed_rmsnorm", x.shape, x.dtype,
+                               {"p": p, **config})
+        return dispatch.call_with_fallback(
+            "gs_fixed_rmsnorm", lambda: _gs_fixed_rmsnorm(x, scale, gain,
+                                                          eps=eps, **cfg),
+            lambda: _fixed_rmsnorm_ref(x, scale, gain, eps))
+
+    return _rows(body, x, jnp.asarray(scale, jnp.float32), gain)
 
 
 def gs_adam_update(param, grad, m, v, step, *, lr, beta1: float = 0.9,
@@ -178,18 +220,38 @@ def gs_adam_update(param, grad, m, v, step, *, lr, beta1: float = 0.9,
 
 def flash_attention(q, k, v, *, causal: bool = True, sm_scale=None,
                     p: int | None = None, **config):
-    cfg = dispatch.resolve("flash_attention", q.shape, q.dtype,
-                           {"p": p, **config})
-    # Tuned/default blocks come from a pow2 shape bucket, so clamp them to
-    # tile the actual sequence length — but never rewrite a block size the
-    # caller passed explicitly (the kernel's divisibility assert applies).
-    s = q.shape[2]
-    for key in ("block_q", "block_kv"):
-        if config.get(key) is None:
-            cfg[key] = common.fit_block(s, cfg[key])
-    return dispatch.call_with_fallback(
-        "flash_attention",
-        lambda: _flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                                 **cfg),
-        lambda: _ref.attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                               **_gs_kw(cfg)))
+    def body(q, k, v):
+        cfg = dispatch.resolve("flash_attention", q.shape, q.dtype,
+                               {"p": p, **config})
+        # Tuned/default blocks come from a pow2 shape bucket, so clamp
+        # them to tile the actual sequence length — but never rewrite a
+        # block size the caller passed explicitly (the kernel's
+        # divisibility assert applies).
+        s = q.shape[2]
+        for key in ("block_q", "block_kv"):
+            if config.get(key) is None:
+                cfg[key] = common.fit_block(s, cfg[key])
+        return dispatch.call_with_fallback(
+            "flash_attention",
+            lambda: _flash_attention(q, k, v, causal=causal,
+                                     sm_scale=sm_scale, **cfg),
+            lambda: _ref.attention(q, k, v, causal=causal,
+                                   sm_scale=sm_scale, **_gs_kw(cfg)))
+
+    ctx = shr.active_context()
+    if ctx is not None:
+        # heads over 'model', contiguously: chip i's query heads read
+        # chip i's KV heads.  Where the KV heads do not divide the axis,
+        # each query head gets its own copy of its KV head (head i reads
+        # i // group, as the kernel's index map does)
+        h, kh = q.shape[1], k.shape[1]
+        model = dict(ctx[0].shape).get("model", 1)
+        if kh % model and not h % model:
+            k, v = (jnp.repeat(a, h // kh, axis=1) for a in (k, v))
+    q_spec = lambda mesh, dp: shr.filter_pspec(  # noqa: E731
+        P(dp or None, "model"), mesh, q.shape)
+    kv_spec = lambda mesh, dp: shr.filter_pspec(  # noqa: E731
+        P(dp or None, "model"), mesh, k.shape)
+    return _on_mesh(body, (q, k, v), lambda mesh, dp: (
+        (q_spec(mesh, dp), kv_spec(mesh, dp), kv_spec(mesh, dp)),
+        q_spec(mesh, dp)))

@@ -14,8 +14,9 @@ Three execution modes:
 
 * ``decode`` — one new token vs a (b, S, kh, hd) KV cache, dense softmax
   over the masked cache with the policy softmax.  Under GSPMD the cache
-  stays sharded (batch over 'data', head_dim over 'model'); the
-  contraction over the sharded head_dim inserts one small psum per step.
+  stays sharded (batch over 'data'; over 'model' the KV heads where they
+  divide it, and each chip attends its own heads with no collective,
+  else head_dim, whose contraction inserts one small psum per step).
 """
 
 from __future__ import annotations
@@ -29,7 +30,7 @@ import jax.numpy as jnp
 from repro.core.formats import kv_cast, kv_dequantize
 from repro.core.policy import NumericsPolicy
 from repro.layers import init as linit
-from repro.runtime.sharding import constrain
+from repro.runtime.sharding import constrain, kv_layout
 
 NEG_INF = -1e30
 
@@ -83,8 +84,8 @@ def expand_kv_heads(k: jnp.ndarray, n_heads: int) -> jnp.ndarray:
     into (KH, g) factors breaks GSPMD propagation (KH < mesh axis) and
     silently replicates attention over 'model' (measured: 8.4x device
     FLOPs on the first dry-run).  A static gather keeps one whole H axis:
-    the input is model-replicated by the wk/wv sharding rule, the output
-    shards on H, and XLA fuses the duplication into the consumer matmul.
+    the output shards on H, and XLA fuses the duplication into the
+    consumer matmul.
     """
     kh = k.shape[2]
     group = n_heads // kh
@@ -331,20 +332,23 @@ def decode_attention(
     g = h // kh
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(hd)
+    kv_h, kv_d = kv_layout(kh)
     # move q from the projection's head sharding onto the cache layout
-    # (head_dim over 'model') before the contraction — resharding the
-    # (b, 1, h, hd) query is one tiny collective; letting GSPMD align the
-    # batch-dim kh instead reshards the whole KV cache every tick
-    qg = constrain(q.reshape(b, kh, g, hd), "dp", None, None, "model")
+    # before the contraction.  KV heads over 'model': the query's
+    # contiguous head shards already match.  head_dim over 'model':
+    # resharding the (b, 1, h, hd) query is one tiny collective; letting
+    # GSPMD align the batch-dim kh instead reshards the whole KV cache
+    # every tick
+    qg = constrain(q.reshape(b, kh, g, hd), "dp", kv_h, None, kv_d)
     # kv_dequantize: plain f32 cast for float caches; int8 arenas (the
     # quantized serving path) scale back by the static KV step
     logits = jnp.einsum(
         "bkgd,btkd->bkgt", qg.astype(jnp.float32), kv_dequantize(k_cache)
     ) * sm_scale  # (b, kh, g, S)
-    # contraction over the 'model'-sharded head_dim: pin the result
+    # a contraction over a 'model'-sharded head_dim: pin the result
     # replicated over 'model' so GSPMD lowers the intended small psum
     # instead of resharding the (much larger) KV cache around the einsum
-    logits = constrain(logits, "dp", None, None, None)
+    logits = constrain(logits, "dp", kv_h, None, None)
     pos = jnp.arange(S)[None, None, None, :]
     cur = jnp.asarray(cur_index)
     if cur.ndim == 1:  # per-slot sequence lengths (continuous batching)
@@ -362,7 +366,7 @@ def decode_attention(
             cur, (-1, 1, 1, 1) if cur.ndim else ())
         v_masked = jnp.where(vmask, kv_dequantize(v_cache), 0.0)
     o = jnp.einsum("bkgt,btkd->bkgd", probs, v_masked)
-    o = constrain(o, "dp", None, None, "model")  # back on the cache layout
+    o = constrain(o, "dp", kv_h, None, kv_d)  # back on the cache layout
     return o.reshape(b, 1, h, hd).astype(q.dtype)
 
 

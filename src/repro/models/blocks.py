@@ -32,6 +32,7 @@ from repro.layers import mlp as mlp_mod
 from repro.layers import moe as moe_mod
 from repro.layers.norms import norm_apply, norm_init
 from repro.layers.rope import apply_rope
+from repro.runtime import sharding as shr
 from repro.runtime.sharding import constrain
 
 
@@ -71,8 +72,8 @@ def init_block_state(cfg: ArchConfig, kind: Tuple[str, str], batch: int,
 
 
 def _residual(cfg: ArchConfig, x, out):
-    if cfg.scale_depth:
-        out = out * (cfg.scale_depth / (cfg.n_layers ** 0.5))
+    if cfg.residual_multiplier != 1.0:
+        out = out * cfg.residual_multiplier
     return x + out.astype(x.dtype)
 
 
@@ -98,6 +99,10 @@ def block_apply(
     sp = cfg.seq_parallel and mode not in ("decode", "chunk")
     s_ax = "model" if sp else None
     h_ax = None if sp else "model"
+    # the KV heads carry 'model' where they divide it, else (decode
+    # caches) head_dim does: runtime/sharding.kv_split
+    kv_h, kv_d = shr.kv_layout(cfg.n_kv_heads)
+    kh_ax = None if sp else kv_h
     h = norm_apply(cfg.norm, params["norm1"], x, eps=cfg.norm_eps, policy=policy,
                    kernel_impl=cfg.kernel_impl)
     new_state: Optional[Dict[str, jnp.ndarray]] = None
@@ -105,8 +110,8 @@ def block_apply(
     if mixer == "attn":
         q, k, v = attn.qkv(params["attn"], h)
         q = constrain(q, "dp", s_ax, h_ax, None)
-        k = constrain(k, "dp", s_ax, None, None)
-        v = constrain(v, "dp", s_ax, None, None)
+        k = constrain(k, "dp", s_ax, kh_ax, None)
+        v = constrain(v, "dp", s_ax, kh_ax, None)
         if rope_cs is not None:
             cos, sin = rope_cs
             # re-pin after rope: its rotate-half concatenate must never be
@@ -115,14 +120,14 @@ def block_apply(
             # encdec._sinusoid), and GSPMD would otherwise pick the
             # decode cache's hd-sharded layout for it
             q = constrain(apply_rope(q, cos, sin), "dp", s_ax, h_ax, None)
-            k = constrain(apply_rope(k, cos, sin), "dp", s_ax, None, None)
+            k = constrain(apply_rope(k, cos, sin), "dp", s_ax, kh_ax, None)
         if mode == "decode":
             assert state is not None and cur_index is not None
             # the constrain templates keep the stacks on the pool's
             # placement (layer axis unsharded, slots or pages over dp,
-            # head_dim over 'model') so the sharded cache round-trips the
-            # tick without rematerialization: the scatter of the new rows
-            # drops the sharding under GSPMD otherwise
+            # KV heads or head_dim over 'model') so the sharded cache
+            # round-trips the tick without rematerialization: the scatter
+            # of the new rows drops the sharding under GSPMD otherwise
             if page_table is not None:
                 # block-table path: KV leaves are the shared page arena
                 # (L, n_pages, page_size, KH, hd); scatter through the
@@ -133,19 +138,20 @@ def block_apply(
                 kc, vc = attn.paged_cache_update(
                     state["k"], state["v"], layer, k, v, page_table,
                     cur_index, page_size)
-                kc = constrain(kc, None, "dp", None, None, "model")
-                vc = constrain(vc, None, "dp", None, None, "model")
+                kc = constrain(kc, None, "dp", None, kv_h, kv_d)
+                vc = constrain(vc, None, "dp", None, kv_h, kv_d)
                 kv = constrain(attn.gather_pages(kc, layer, page_table),
-                               "dp", None, None, "model")
+                               "dp", None, kv_h, kv_d)
                 vv = constrain(attn.gather_pages(vc, layer, page_table),
-                               "dp", None, None, "model")
+                               "dp", None, kv_h, kv_d)
             else:
                 kc, vc = attn.cache_update(
                     state["k"], state["v"], layer, k, v, cur_index)
-                kc = constrain(kc, None, "dp", None, None, "model")
-                vc = constrain(vc, None, "dp", None, None, "model")
+                kc = constrain(kc, None, "dp", None, kv_h, kv_d)
+                vc = constrain(vc, None, "dp", None, kv_h, kv_d)
                 kv, vv = kc[layer], vc[layer]
-            o = attn.decode_attention(q, kv, vv, cur_index, policy=policy)
+            o = attn.decode_attention(q, kv, vv, cur_index, policy=policy,
+                                      sm_scale=cfg.attention_multiplier)
             new_state = {"k": kc, "v": vc}
         elif mode == "chunk":
             # chunked prefill: the carry holds the KV of every earlier
@@ -155,7 +161,8 @@ def block_apply(
             assert state is not None
             k_all = jnp.concatenate([state["k"], k], axis=1)
             v_all = jnp.concatenate([state["v"], v], axis=1)
-            o = attn.chunk_attention(q, k_all, v_all, policy=policy)
+            o = attn.chunk_attention(q, k_all, v_all, policy=policy,
+                                     sm_scale=cfg.attention_multiplier)
             new_state = {"k": k_all, "v": v_all}
         else:
             o = attn.flash(
@@ -164,6 +171,7 @@ def block_apply(
                 q_block=cfg.attn_q_block, kv_block=cfg.attn_kv_block,
                 block_skip=cfg.attn_block_skip,
                 seq_shard=cfg.attn_seq_shard,
+                sm_scale=cfg.attention_multiplier,
             )
             if mode == "prefill":
                 new_state = {"k": k, "v": v}

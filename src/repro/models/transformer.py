@@ -80,7 +80,10 @@ def _rope_info(cfg: ArchConfig, batch: int, seq: int,
 @jax.named_scope("embed")
 def embed_tokens(cfg: ArchConfig, params: Params, tokens: jnp.ndarray,
                  cur_index: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+    x = jnp.take(params["embed"], tokens, axis=0)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    x = x.astype(cfg.dtype)
     if cfg.pos == "learned":
         if cur_index is not None and jnp.ndim(cur_index) == 1:
             # per-slot positions: (b,) gather, decode seq is 1
@@ -92,7 +95,10 @@ def embed_tokens(cfg: ArchConfig, params: Params, tokens: jnp.ndarray,
         else:
             pe = params["pos_embed"][: tokens.shape[1]][None]
         x = x + pe.astype(cfg.dtype)
-    return x
+    # the residual stream replicates over 'model': a table sharded on
+    # d_model (runtime/sharding._ELSE) is gathered once, here, and not
+    # at every layer the stream enters
+    return constrain(x, "dp", None, None)
 
 
 @jax.named_scope("lm_head")
@@ -105,6 +111,9 @@ def unembed(cfg: ArchConfig, params: Params, x: jnp.ndarray) -> jnp.ndarray:
     else:
         logits = jnp.einsum("bsd,dv->bsv", h,
                             linit.cast(params["lm_head"], h.dtype))
+    if cfg.logits_scaling != 1.0:
+        # a compile-time reciprocal, as the norms' mean: no runtime divide
+        logits = logits * (1.0 / cfg.logits_scaling)
     return constrain(logits, "dp", None, "model")
 
 

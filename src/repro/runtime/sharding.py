@@ -10,14 +10,21 @@ Policy (DESIGN.md §5) — DP + FSDP + TP + EP:
 * ``data``  — batch sharding *and* FSDP: every large parameter also shards
   one non-TP dimension over 'data' (GSPMD all-gathers it around use).
 * ``model`` — tensor parallelism: attention q-heads, MLP d_ff, Mamba
-  d_inner channels, MoE experts (EP); GQA KV projections are small and
-  stay replicated over 'model' so train-time attention needs no psum
-  before the out-projection (Megatron f/g pattern).
+  d_inner channels, MoE experts (EP), and the GQA KV heads wherever their
+  count divides the axis.  Query heads shard contiguously, so chip *i*'s
+  query heads read exactly chip *i*'s KV heads and attention needs no
+  collective before the out-projection (Megatron f/g pattern).  Where the
+  KV heads do not divide, the KV projections replicate over 'model'.
 
-Decode caches shard batch over 'data' and head_dim over 'model' (KV heads
-are too few to shard; head_dim always divides); SSM states shard d_inner
+Decode caches shard batch over 'data' and, over 'model', the KV heads
+where they divide, else head_dim (``kv_split``); SSM states shard d_inner
 over 'model'.  b=1 cells (long_500k) drop the batch axis and lean on
 'model' alone — recorded per-cell in EXPERIMENTS.md.
+
+A leaf whose rule's 'model' dimension does not divide the axis takes its
+``_ELSE`` rule where it has one: an embedding table whose vocabulary the
+axis does not divide shards d_model instead of replicating (a tied head
+then reduces its logits over 'model').
 
 Rules key on the LAST path component + rank, so the same table covers the
 decoder-only stack (leaves carry a leading scan-group axis) and the
@@ -36,8 +43,8 @@ from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 _RULES = {
     # attention
     "wq": ("data", "model", None),       # (D, H, hd)
-    "wk": ("data", None, None),          # (D, KH, hd) — KV replicated over model
-    "wv": ("data", None, None),
+    "wk": ("data", "model", None),       # (D, KH, hd)
+    "wv": ("data", "model", None),
     "wo": ("model", None, "data"),       # (H, hd, D)
     # dense mlp
     "w_in": ("data", "model"),           # (D, F)
@@ -62,6 +69,11 @@ _RULES = {
     # norms
     "scale": (None,),
     "bias": (None,),
+}
+
+# leaf name -> spec where the rule's 'model' dimension does not divide
+_ELSE = {
+    "embed": ("data", "model"),          # (V, D): d_model over 'model'
 }
 
 _MOE_RULES = {  # rank-3 expert-stacked weights: EP over 'model'
@@ -92,6 +104,14 @@ def _path_names(path) -> Tuple[str, ...]:
     return tuple(names)
 
 
+def _ranked(rule, ndim: int) -> P:
+    if ndim == len(rule) + 1:  # stacked over scan groups / layers
+        rule = (None,) + rule
+    if ndim != len(rule):
+        return P()  # unexpected rank: replicate rather than crash
+    return P(*rule)
+
+
 def param_pspec(path, ndim: int) -> P:
     names = _path_names(path)
     last = names[-1]
@@ -100,11 +120,26 @@ def param_pspec(path, ndim: int) -> P:
         rule = _MOE_RULES[last]
     if rule is None:
         return P()
-    if ndim == len(rule) + 1:  # stacked over scan groups / layers
-        rule = (None,) + rule
-    if ndim != len(rule):
-        return P()  # unexpected rank: replicate rather than crash
-    return P(*rule)
+    return _ranked(rule, ndim)
+
+
+def mesh_pspec(path, mesh: Mesh, shape,
+               fsdp_axes: Tuple[str, ...] = ("data",)) -> P:
+    """``param_pspec`` for ``mesh`` (FSDP over ``fsdp_axes``, axes that
+    do not divide dropped); where the rule's 'model' dimension does not
+    divide, the leaf's ``_ELSE`` rule, if it has one."""
+    def fit(spec):
+        if fsdp_axes != ("data",):
+            spec = P(*(fsdp_axes if ax == "data" else ax for ax in spec))
+        return filter_pspec(spec, mesh, shape)
+
+    spec = param_pspec(path, len(shape))
+    out = fit(spec)
+    last = _path_names(path)[-1]
+    if (last in _ELSE and "model" in mesh.shape
+            and "model" in tuple(spec) and "model" not in tuple(out)):
+        out = fit(_ranked(_ELSE[last], len(shape)))
+    return out
 
 
 def tree_pspecs(tree) -> Any:
@@ -151,10 +186,8 @@ def tree_shardings(mesh: Mesh, tree,
     qwen3-235B; see EXPERIMENTS.md §Dry-run).
     """
     def one(path, leaf):
-        spec = param_pspec(path, len(leaf.shape))
-        if fsdp_axes != ("data",):
-            spec = P(*(fsdp_axes if ax == "data" else ax for ax in spec))
-        return NamedSharding(mesh, filter_pspec(spec, mesh, leaf.shape))
+        return NamedSharding(mesh, mesh_pspec(path, mesh, leaf.shape,
+                                              fsdp_axes))
 
     return jax.tree_util.tree_map_with_path(one, tree)
 
@@ -191,8 +224,25 @@ def batch_shardings(mesh: Mesh, cfg, batch_specs, global_batch: int) -> Any:
     return jax.tree_util.tree_map_with_path(one, batch_specs)
 
 
+def kv_split(mesh: Optional[Mesh], n_kv_heads: int
+             ) -> Tuple[Optional[str], Optional[str]]:
+    """The decode cache's use of 'model', as (KV-head axis, head_dim
+    axis): the KV heads where their count divides the axis, so a chip
+    holds whole heads and attends them alone; head_dim otherwise."""
+    m = dict(mesh.shape).get("model", 1) if mesh is not None else 1
+    return ("model", None) if n_kv_heads % m == 0 else (None, "model")
+
+
+def kv_layout(n_kv_heads: int) -> Tuple[Optional[str], Optional[str]]:
+    """``kv_split`` for the active activation context's mesh (constrain
+    templates of the KV heads and head_dim axes)."""
+    ctx = _ACT_CTX.get()
+    return kv_split(ctx["mesh"] if ctx else None, n_kv_heads)
+
+
 def cache_shardings(mesh: Mesh, cfg, cache_specs, global_batch: int) -> Any:
-    """KV caches: (G, b, S, KH, hd) -> batch over dp, hd over 'model'.
+    """KV caches: (G, b, S, KH, hd) -> batch over dp, KV heads (or hd,
+    ``kv_split``) over 'model'.
     SSM states: conv (G, b, k-1, di), ssm (G, b, di, n) -> di over 'model'."""
     dp = dp_axes(mesh, global_batch)
     dspec = dp if dp else None
@@ -202,9 +252,10 @@ def cache_shardings(mesh: Mesh, cfg, cache_specs, global_batch: int) -> Any:
         shape = leaf.shape
         last = names[-1]
         if last in ("k", "v", "ck", "cv"):
-            spec = P(None, dspec, None, None, "model")
+            kv_ax = kv_split(mesh, shape[-2])
+            spec = P(None, dspec, None, *kv_ax)
             if len(shape) == 4:  # encdec caches have no group axis... keep general
-                spec = P(dspec, None, None, "model")
+                spec = P(dspec, None, *kv_ax)
         elif last == "conv":
             spec = P(None, dspec, None, "model")
         elif last == "ssm":
@@ -219,8 +270,9 @@ def cache_shardings(mesh: Mesh, cfg, cache_specs, global_batch: int) -> Any:
 def pool_shardings(mesh: Mesh, cfg, cache_specs, n_slots: int) -> Any:
     """Serving slot-pool placement = the documented decode-cache policy.
 
-    Slots (the pool's batch axis) shard over 'data', KV head_dim and SSM
-    ``d_inner`` over 'model'; everything else replicates.  A pool narrower
+    Slots (the pool's batch axis) shard over 'data', KV heads (or
+    head_dim, ``kv_split``) and SSM ``d_inner`` over 'model'; everything
+    else replicates.  A pool narrower
     than the 'data' axis falls back to replicated rows (filter_pspec), so
     a TP-only serving mesh (1, M) is always legal.  Same rule table as
     training decode — the whole point of wiring serving onto the mesh is
@@ -229,8 +281,8 @@ def pool_shardings(mesh: Mesh, cfg, cache_specs, n_slots: int) -> Any:
     The PAGED pool's arenas reuse the same rule unchanged: a paged k/v
     leaf is (lead, n_pages, page_size, KH, hd) — still rank 5, with the
     page axis sitting where the slot axis sat — so the rank-5 k/v rule
-    ``P(None, dspec, None, None, 'model')`` shards pages over 'data' and
-    head_dim over 'model' with no paged-specific case here.  Non-paged
+    shards pages over 'data' and KV heads (or head_dim) over 'model' with
+    no paged-specific case here.  Non-paged
     leaves (ck/cv cross-KV, conv, ssm) keep slot-resident shapes and hit
     their usual rows.
     """
@@ -273,6 +325,12 @@ def activation_context(mesh: Optional[Mesh], dp: Tuple[str, ...]):
         yield
     finally:
         _ACT_CTX.reset(token)
+
+
+def active_context() -> Optional[Tuple[Mesh, Tuple[str, ...]]]:
+    """``(mesh, dp axes)`` of the activation context, or None outside one."""
+    ctx = _ACT_CTX.get()
+    return None if ctx is None else (ctx["mesh"], ctx["dp"])
 
 
 def constrain(x, *template):

@@ -123,7 +123,7 @@ import contextlib
 import dataclasses
 import time
 from collections import deque
-from typing import Any, Deque, Dict, List, Optional, Sequence
+from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -134,6 +134,7 @@ from repro.configs.base import ArchConfig
 from repro.kernels.tuning import dispatch as _dispatch
 from repro.launch.steps import (make_chunk_init_step, make_chunk_prefill_step,
                                 make_decode_step, make_prefill_step)
+from repro.obs import hlo
 from repro.obs.metrics import summarize as _summarize
 from repro.obs.trace import ENGINE_TRACK
 from repro.layers.quant import quantize_params
@@ -227,6 +228,10 @@ class ServeMetrics:
     preempted: int = 0     # paged preempt-youngest events
     retried: int = 0       # submit retries + tick retries consumed
     kernel_fallbacks: int = 0  # pallas->jnp downgrades during this run
+    # collective ops one decode tick runs, and their bytes per chip, read
+    # from the compiled tick's HLO (repro.obs.hlo); 0 without a mesh
+    tick_collectives: int = 0
+    tick_collective_bytes: int = 0
     # per-kernel attribution: which kernel downgraded (not just how many
     # times in total), plus the dispatch-layer resolve / autotune-cache
     # hit/miss deltas for the run (kernels/tuning/dispatch.py)
@@ -368,6 +373,7 @@ class Engine:
             cfg, mesh=mesh, dp=self._dp,
             page_size=self.ecfg.page_size if self._paged else 0)
         self._tick_fns: Dict[tuple, object] = {}
+        self._collectives: Dict[int, Tuple[int, int]] = {}
         self._first_fns: Dict[tuple, object] = {}
         self._key = jax.random.key(self.ecfg.seed)
         self._cancel_rids: set = set()
@@ -482,6 +488,19 @@ class Engine:
             self._tick_fns[fkey] = jax.jit(
                 tick, donate_argnums=(1,), **jit_kw)
         return self._tick_fns[fkey]
+
+    def _tick_collectives(self, tick, args) -> Tuple[int, int]:
+        """(collective ops, their bytes per chip) of one execution of the
+        jitted tick ``tick`` on ``args``, read from its compiled HLO the
+        first time it is asked for.  JAX keeps that lowering and its
+        executable, so the tick's own call traces and compiles nothing
+        more.  (0, 0) without a mesh."""
+        if self.mesh is None:
+            return (0, 0)
+        if id(tick) not in self._collectives:
+            self._collectives[id(tick)] = hlo.summary(
+                tick.lower(*args).compile().as_text())
+        return self._collectives[id(tick)]
 
     def _first_fn(self, stochastic: bool, top_k: int = 0):
         fkey = (stochastic, top_k)
@@ -742,7 +761,11 @@ class Engine:
 
         # the run phase opens at engine-clock ~0: one offset maps every
         # span of the run onto the profiler's clock
-        with (tr.phase("run") if tr is not None else _NO_PHASE):
+        mesh = ("x".join(str(v) for v in self.mesh.shape.values())
+                if self.mesh is not None else None)
+        with (tr.phase("run", mesh=mesh, chips=(self.mesh.size if mesh
+                                                 else 1))
+              if tr is not None else _NO_PHASE):
             states: List[RequestState] = [
                 RequestState(r, t_arrive=r.arrival_time,
                              deadline_at=(
@@ -769,8 +792,12 @@ class Engine:
         metrics.dispatch = _dispatch.dispatch_delta(disp_start)
         metrics.makespan_s = clock()
         if tr is not None:
+            # the compiled tick's collectives ride the run's last record
+            # on the engine track: no record of their own
             tr.instant("run_end", ENGINE_TRACK,
-                       decode_ticks=metrics.decode_ticks)
+                       decode_ticks=metrics.decode_ticks,
+                       tick_collectives=metrics.tick_collectives,
+                       tick_collective_bytes=metrics.tick_collective_bytes)
         stats = pool.stats()
         metrics.pool = stats
         metrics.prefix_hits = stats.get("prefix_hits", 0)
@@ -1075,6 +1102,9 @@ class Engine:
                     jnp.asarray(cur), jnp.asarray(last_tok[:, None]),
                     jnp.asarray(temps), jnp.asarray(topks),
                     jnp.asarray(rids), self._key)
+                (metrics.tick_collectives,
+                 metrics.tick_collective_bytes) = self._tick_collectives(
+                     tick, (self.params, pool.cache) + operands)
             attempts = 0
             t_tick0 = clock() if tr is not None else 0.0
             t0 = time.perf_counter()

@@ -321,3 +321,133 @@ class TestShardedServing:
         """)
         for arch, ok in res.items():
             assert ok, f"sharded serving parity broke for {arch}"
+
+
+@pytest.mark.slow
+class TestTensorParallelGranite:
+    """The published Granite arithmetic (tied head, four scalars) served
+    tensor-parallel on a 1x4 mesh of 4 forced host devices, through the
+    Pallas kernels (interpreted on the CPU) under shard_map."""
+
+    SETUP = """
+        import json, jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import PartitionSpec as P
+        from repro import configs
+        from repro.models import api
+        from repro.launch.mesh import make_serving_mesh
+        from repro.serving import Engine, EngineConfig, Request
+
+        cfg = configs.get_smoke("granite-3-8b", dtype="float32",
+                                param_dtype="float32", kernel_impl="pallas")
+        params = api.init(cfg, jax.random.key(5))
+        ecfg = EngineConfig(n_slots=2, pool="paged", page_size=4,
+                            page_reserve="worst")
+        mesh = make_serving_mesh("1x4")
+    """
+
+    def test_paged_tp_tokens_match_one_device_and_count_collectives(self):
+        """Greedy tokens on the mesh equal one device's, and the tick's
+        collectives are the count the layout implies: per layer the two
+        row-parallel reductions (attention out-projection, MLP down
+        projection), then the tied head's reduction over the d_model
+        shards, and one gather of the embedded token rows, whose table
+        is sharded on d_model (vocab 257 does not divide 4).  One device
+        runs none.  Reading the count compiles nothing more: a mesh
+        engine that skips it compiles as many programs."""
+        res = run_py(textwrap.dedent(self.SETUP) + textwrap.dedent("""
+            from jax._src import monitoring
+            compiles = [0]
+            monitoring.register_event_duration_secs_listener(
+                lambda ev, d, **kw: compiles.__setitem__(
+                    0, compiles[0] + ev.endswith("backend_compile_duration")))
+            rng = np.random.RandomState(5)
+            reqs = [Request(rid=i, prompt=rng.randint(0, cfg.vocab, (5 + 3 * i,)),
+                            max_new_tokens=6, arrival_time=0.01 * i)
+                    for i in range(4)]
+            o1, m1 = Engine(cfg, params, ecfg).run(reqs)
+            o2, m2 = Engine(cfg, params, ecfg, mesh=mesh).run(reqs)
+            # a second engine of each kind, after the first has filled
+            # the caches that engines share
+            c0 = compiles[0]
+            Engine(cfg, params, ecfg, mesh=mesh).run(reqs)
+            counted = compiles[0] - c0
+            c0 = compiles[0]
+            Engine._tick_collectives = lambda self, tick, args: (0, 0)
+            Engine(cfg, params, ecfg, mesh=mesh).run(reqs)
+            uncounted = compiles[0] - c0
+            print(json.dumps({
+                "parity": all(np.array_equal(o1[r.rid].tokens,
+                                             o2[r.rid].tokens) for r in reqs),
+                "distinct": len({int(t) for o in o1.values()
+                                 for t in o.tokens}),
+                "one": m1.tick_collectives, "mesh": m2.tick_collectives,
+                "mesh_bytes": m2.tick_collective_bytes,
+                "fallbacks": m2.kernel_fallbacks,
+                "layers": cfg.n_layers,
+                "compiles": [counted, uncounted]}))
+        """), devices=4)
+        assert res["parity"], "tensor-parallel vs one-device token mismatch"
+        assert res["distinct"] > 1  # the tokens are not one repeated id
+        assert res["fallbacks"] == 0
+        assert res["one"] == 0
+        assert res["mesh"] == 2 * res["layers"] + 1 + 1
+        assert res["mesh_bytes"] > 0
+        counted, uncounted = res["compiles"]
+        assert uncounted > 0 and counted == uncounted
+
+    def test_kv_heads_and_tied_table_sharded_tick_in_place(self):
+        """wk/wv shard their KV heads, the tied table its d_model, both
+        arena stacks their KV heads over 'model'; the compiled tick keeps
+        the pool's shardings on both sides of the donated cache, aliases
+        it, copies no whole stack, and the partitioner reports no
+        involuntary rematerialization (screened on stderr)."""
+        res = run_py(textwrap.dedent(self.SETUP) + textwrap.dedent("""
+            import re
+            from repro.serving.cache import make_paged_cache
+            eng = Engine(cfg, params, ecfg, mesh=mesh)
+            spec = {jax.tree_util.keystr(p): tuple(l.sharding.spec)
+                    for p, l in jax.tree_util.tree_flatten_with_path(
+                        eng.params)[0]}
+            n = ecfg.n_slots
+            cache = jax.device_put(make_paged_cache(
+                cfg, n, eng._n_pages, ecfg.page_size, jnp.dtype(cfg.dtype)),
+                eng._cache_sh)
+            args = (eng.params, cache,
+                    jnp.zeros((n, eng._pages_per_slot), jnp.int32),
+                    jnp.zeros(n, jnp.int32), jnp.zeros((n, 1), jnp.int32),
+                    jnp.zeros(n, jnp.float32), jnp.zeros(n, jnp.int32),
+                    jnp.zeros(n, jnp.int32), eng._key)
+            compiled = eng._tick_fn(False, 0, True).lower(*args).compile()
+            leaves = jax.tree.leaves(cache)
+            k_in = jax.tree.leaves(compiled.input_shardings[0])[
+                len(jax.tree.leaves(eng.params)):][:len(leaves)]
+            k_out = jax.tree.leaves(compiled.output_shardings)[-len(leaves):]
+            no_reshard = all(a.is_equivalent_to(b, l.ndim) and
+                             a.is_equivalent_to(s, l.ndim)
+                             for a, b, s, l in zip(k_in, k_out,
+                                                   jax.tree.leaves(eng._cache_sh),
+                                                   leaves))
+            local = leaves[0].sharding.shard_shape(leaves[0].shape)
+            stack = "[" + ",".join(map(str, local)) + "]"
+            copies = [line for line in compiled.as_text().splitlines()
+                      if re.search(r" copy(-start)?\\(", line)
+                      and stack in re.split(r" copy(-start)?\\(", line)[0]]
+            local_bytes = sum(
+                int(np.prod(l.sharding.shard_shape(l.shape)))
+                * l.dtype.itemsize for l in leaves)
+            print(json.dumps({
+                "wk": spec["['layers']['pos0']['attn']['wk']"],
+                "wv": spec["['layers']['pos0']['attn']['wv']"],
+                "embed": spec["['embed']"],
+                "arena": [tuple(s.spec) for s in
+                          jax.tree.leaves(eng._cache_sh)],
+                "no_reshard": no_reshard, "copies": len(copies),
+                "aliased": compiled.memory_analysis().alias_size_in_bytes
+                           == local_bytes}))
+        """), devices=4, forbid_stderr=("Involuntary full rematerialization",))
+        assert res["wk"] == res["wv"] == [None, "data", "model", None]
+        assert res["embed"] == ["data", "model"]
+        assert res["arena"] == [[None, "data", None, "model", None]] * 2
+        assert res["no_reshard"], "decode tick resharded the arena"
+        assert res["copies"] == 0, "the tick copies a whole KV stack"
+        assert res["aliased"], "the arena is not aliased to the tick's output"

@@ -23,7 +23,10 @@ class TestRules:
     def test_dense_attention_rules(self):
         m = _pspec_map(configs.get_config("tinyllama-1.1b"))
         assert m["layers/pos0/attn/wq"] == P(None, "data", "model", None)
-        assert m["layers/pos0/attn/wk"] == P(None, "data", None, None)
+        # KV heads over 'model' (dropped by the filter where they do not
+        # divide it: TestKVHeadSharding)
+        assert m["layers/pos0/attn/wk"] == P(None, "data", "model", None)
+        assert m["layers/pos0/attn/wv"] == P(None, "data", "model", None)
         assert m["layers/pos0/attn/wo"] == P(None, "model", None, "data")
         assert m["layers/pos0/mlp/w_in"] == P(None, "data", "model")
         assert m["layers/pos0/mlp/w_out"] == P(None, "model", "data")
@@ -85,6 +88,68 @@ class TestDivisibilityFilter:
         bare ints where Mesh expects an iterable spec)."""
         mesh = shr.abstract_mesh((4, 2), ("data", "model"))
         assert dict(mesh.shape) == {"data": 4, "model": 2}
+
+
+def _mesh_specs(mesh, tree):
+    return {
+        "/".join(str(getattr(k, "key", k)) for k in path): sh.spec
+        for path, sh in jax.tree_util.tree_flatten_with_path(
+            shr.tree_shardings(mesh, tree))[0]}
+
+
+class TestKVHeadSharding:
+    """A 1x4 serving mesh over the published granite-3-8b: 8 KV heads and
+    a tied 49155-row table, which 4 does not divide."""
+
+    MESH = ((1, 4), ("data", "model"))
+
+    def test_kv_projections_shard_their_heads(self):
+        mesh = shr.abstract_mesh(*self.MESH)
+        m = _mesh_specs(mesh, api.param_specs(
+            configs.get_config("granite-3-8b")))
+        assert m["layers/pos0/attn/wk"] == P(None, "data", "model", None)
+        assert m["layers/pos0/attn/wv"] == P(None, "data", "model", None)
+        assert m["layers/pos0/attn/wq"] == P(None, "data", "model", None)
+
+    def test_kv_heads_that_do_not_divide_replicate(self):
+        mesh = shr.abstract_mesh((2, 8), ("data", "model"))
+        m = _mesh_specs(mesh, api.param_specs(
+            configs.get_config("tinyllama-1.1b")))  # 4 KV heads
+        assert m["layers/pos0/attn/wk"] == P(None, "data", None, None)
+
+    def test_tied_table_shards_d_model_where_vocab_does_not_divide(self):
+        mesh = shr.abstract_mesh(*self.MESH)
+        m = _mesh_specs(mesh, api.param_specs(
+            configs.get_config("granite-3-8b")))
+        assert "lm_head" not in m
+        assert m["embed"] == P("data", "model")
+        m = _mesh_specs(mesh, api.param_specs(
+            configs.get_config("tinyllama-1.1b")))  # vocab 32000 divides
+        assert m["embed"] == P("model", "data")
+
+    def test_a_mesh_without_model_keeps_the_rule(self):
+        mesh = shr.abstract_mesh((4,), ("data",))
+        m = _mesh_specs(mesh, api.param_specs(
+            configs.get_config("granite-3-8b")))
+        assert m["embed"] == P(None, "data")
+
+    def test_kv_split(self):
+        mesh = shr.abstract_mesh(*self.MESH)
+        assert shr.kv_split(mesh, 8) == ("model", None)
+        assert shr.kv_split(mesh, 2) == (None, "model")
+        assert shr.kv_split(None, 2) == ("model", None)
+
+    @pytest.mark.parametrize("kv_heads, spec", [
+        (8, P(None, "data", None, "model", None)),   # KV heads
+        (2, P(None, "data", None, None, "model")),   # head_dim
+    ])
+    def test_arena_shards_kv_heads_where_they_divide(self, kv_heads, spec):
+        mesh = shr.abstract_mesh(*self.MESH)
+        arena = jax.ShapeDtypeStruct((40, 1793, 16, kv_heads, 128),
+                                     jnp.bfloat16)
+        sh = shr.pool_shardings(mesh, None, {"pos0": {"k": arena,
+                                                      "v": arena}}, 16)
+        assert sh["pos0"]["k"].spec == sh["pos0"]["v"].spec == spec
 
 
 class TestActivationConstraints:

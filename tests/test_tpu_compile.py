@@ -15,6 +15,7 @@ module is imported: only one process at a time may load the TPU library,
 and every test worker imports this file.
 """
 
+import math
 import re
 
 import jax
@@ -129,33 +130,121 @@ def test_gs_fixed_rmsnorm(one_chip):
              one_chip, ((256, D_MODEL), jnp.int8), ((D_MODEL,), jnp.float32))
 
 
-def test_paged_tick_updates_arena_in_place(one_chip):
-    """The decode tick at internlm2-1.8b widths (2 layers, a 65-page
-    arena) updates the donated KV arena in place: the optimized HLO holds
-    no copy of a whole KV stack, and the whole cache is aliased to the
-    tick's cache output."""
-    cfg = configs.get_config("internlm2-1.8b", n_layers=2)
+def _described_1x4(topo):
+    """A ("data", "model") = (1, 4) mesh over the described chips, with
+    Auto axes as every mesh of ``repro.launch.mesh``."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+
+    return Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+
+
+def _sharded_engine(cfg, params, ecfg, mesh):
+    """The engine on ``mesh`` over described shapes: it places its
+    weights with device_put, which takes shapes only when traced."""
+    built = []
+    jax.eval_shape(lambda p: built.append(
+        Engine(cfg, p, ecfg, mesh=mesh)) or 0, params)
+    return built[0]
+
+
+def _kernel_calls(text, name):
+    return re.findall(rf"%{name}[.\d]* = .*tpu_custom_call", text)
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Kernels compiled for the chip, not interpreted: on this CPU host
+    the engine's steps would derive interpret mode from the backend."""
+    from repro.kernels import common
+
+    monkeypatch.setattr(common, "interpret_flag",
+                        lambda interpret=None: bool(interpret))
+
+
+@pytest.mark.parametrize("mesh", [False, True], ids=["one_chip", "mesh_1x4"])
+def test_paged_tick_updates_arena_in_place(topo, one_chip, mosaic, mesh):
+    """The decode tick (2 layers, a 65-page arena) updates the donated KV
+    arena in place: the optimized HLO holds no copy of a whole KV stack,
+    and the whole cache is aliased to the tick's cache output.  One chip
+    at internlm2-1.8b widths; on a described 1x4 mesh the tied, scaled
+    granite-3-8b at its widths, each chip holding its share of the
+    weights and two of the 8 KV heads of the arena, with the GS rmsnorm
+    kernel compiled under shard_map."""
     n, ps = 4, 16
-    sds = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
-        a.shape, a.dtype, sharding=one_chip)
-    params = jax.tree.map(sds, api.param_specs(cfg))
-    eng = Engine(cfg, params, EngineConfig(
-        n_slots=n, s_max=256, pool="paged", page_size=ps, n_pages=65))
-    cache = jax.tree.map(sds, jax.eval_shape(lambda: make_paged_cache(
-        cfg, n, eng._n_pages, ps, jnp.dtype(cfg.dtype))))
+    ecfg = EngineConfig(n_slots=n, s_max=256, pool="paged", page_size=ps,
+                        n_pages=65)
+    if mesh:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from repro.runtime import sharding as shr
+
+        m = _described_1x4(topo)
+        cfg = configs.get_config("granite-3-8b", n_layers=2,
+                                 kernel_impl="pallas")
+        specs = api.param_specs(cfg)
+        params = jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            specs, shr.tree_shardings(m, specs))
+        eng = _sharded_engine(cfg, params, ecfg, m)
+        cache_sh = eng._cache_sh
+        rep = NamedSharding(m, P())
+    else:
+        cfg = configs.get_config("internlm2-1.8b", n_layers=2)
+        params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), api.param_specs(cfg))
+        eng = Engine(cfg, params, ecfg)
+        cache_sh = None
+        rep = one_chip
+    cache = jax.eval_shape(lambda: make_paged_cache(
+        cfg, n, eng._n_pages, ps, jnp.dtype(cfg.dtype)))
+    cache = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        cache, cache_sh or jax.tree.map(lambda _: rep, cache))
     i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
-        shape, jnp.int32, sharding=one_chip)
+        shape, jnp.int32, sharding=rep)
     compiled = eng._tick_fn(False, 0, True).lower(
         params, cache, i32(n, eng._pages_per_slot), i32(n), i32(n, 1),
-        jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((n,), jnp.float32, sharding=rep),
         i32(n), i32(n),
         jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
-                             sharding=one_chip)).compile()
+                             sharding=rep)).compile()
     leaves = jax.tree.leaves(cache)
-    stack = "[" + ",".join(map(str, leaves[0].shape)) + "]"
-    copies = [line for line in compiled.as_text().splitlines()
+    local = [a.sharding.shard_shape(a.shape) for a in leaves]
+    stack = "[" + ",".join(map(str, local[0])) + "]"
+    text = compiled.as_text()
+    copies = [line for line in text.splitlines()
               if re.search(r" copy(-start)?\(", line)
               and stack in re.split(r" copy(-start)?\(", line)[0]]
     assert not copies, copies[:2]
     assert compiled.memory_analysis().alias_size_in_bytes == sum(
-        a.size * a.dtype.itemsize for a in leaves)
+        math.prod(s) * a.dtype.itemsize for s, a in zip(local, leaves))
+    if mesh:
+        assert local[0][3] == cfg.n_kv_heads // 4
+        assert _kernel_calls(text, "gs_rmsnorm")
+
+
+def test_sharded_prefill_lowers_pallas_kernels(topo, mosaic):
+    """The prefill of the tied, scaled granite-3-8b (2 layers) on a
+    described 1x4 mesh compiles its flash-attention and GS rmsnorm
+    kernels under shard_map, each under its own name (the trace readers
+    find the kernels by it)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.runtime import sharding as shr
+
+    m = _described_1x4(topo)
+    cfg = configs.get_config("granite-3-8b", n_layers=2,
+                             kernel_impl="pallas")
+    specs = api.param_specs(cfg)
+    params = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        specs, shr.tree_shardings(m, specs))
+    eng = _sharded_engine(cfg, params, EngineConfig(
+        n_slots=4, s_max=512, pool="paged"), m)
+    tokens = jax.ShapeDtypeStruct((1, 512), jnp.int32,
+                                  sharding=NamedSharding(m, P()))
+    text = eng._prefill.lower(params, {"tokens": tokens}).compile().as_text()
+    assert _kernel_calls(text, "flash_attention")
+    assert _kernel_calls(text, "gs_rmsnorm")
